@@ -26,9 +26,9 @@ not regress). Runs on 2 fake CPU devices so the collectives are real.
 psum+slice wire, and ``compressed_rs`` over the native psum_scatter +
 OR-Reduce-Scatter wire — on per-rank wire accounting
 (``CompressionConfig.strategy_wire_bytes``), static collective-op
-counts, and wall time. The 1-axis mesh keeps the region full-manual so
-the native path runs on both JAX legs; CI fails if the native arm's
-per-rank payload is not strictly below ``compressed``'s.
+counts, and wall time. The 1-axis mesh keeps the region full-manual, so
+the recovered chunks reassemble with an all_gather; CI fails if the
+native arm's per-rank payload is not strictly below ``compressed``'s.
 
 ``--compare-innet`` (PR 4) compares dense / ``compressed`` /
 ``compressed_innet`` over both its wire dtypes (idealized f32 and the
@@ -418,7 +418,7 @@ def compare_rs(smoke: bool = False) -> List[Dict]:
     (psum+slice emulation vs native psum_scatter + OR-Reduce-Scatter).
 
     The mesh has only the manual "data" axis, so the region is
-    full-manual and the native path runs on both JAX legs. The headline
+    full-manual and reassembles with an all_gather. The headline
     number is ``rank_payload_bytes``: the reduced sketch+bitmap that
     lands on each rank is the full payload for ``compressed`` /
     emulated RS but 1/W of it for native RS — the paper's claim that the
@@ -895,8 +895,8 @@ def compare_a2a(smoke: bool = False) -> List[Dict]:
     Per arm: analytic per-rank payload/link bytes
     (``strategy_wire_bytes``'s ``*_alltoall`` entries), the
     jaxpr-measured link bytes (must reconcile exactly — the mesh's
-    single manual axis keeps the region full-manual, so the native
-    ppermute wire runs on both JAX legs), collective ops/launches, and
+    single manual axis keeps the region full-manual), collective
+    ops/launches, and
     wall time. CI gate: at W > 2 the compressed arm's per-rank a2a
     bytes must be strictly below the dense arm's.
     """
@@ -937,8 +937,7 @@ def compare_a2a(smoke: bool = False) -> List[Dict]:
     rows = []
     outs = {}
     for arm in ("dense_alltoall", "compressed_alltoall"):
-        ex = make_exchange(arm.split("_")[0], cfg, mesh, ("data",),
-                           outer_manual=("data",))
+        ex = make_exchange(arm.split("_")[0], cfg, mesh, ("data",))
         fn = jax.jit(compat.shard_map(
             lambda p, ex=ex: jax.tree.map(lambda l: l[None], ex(p)),
             mesh=mesh, in_specs=({"g": P()},),

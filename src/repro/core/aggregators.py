@@ -26,11 +26,9 @@ whole gradient is packed into fixed-byte flat buckets
   recovery compute), and reassembles the recovered chunks with a
   manual-axis ``all_gather`` (full-manual regions) or the zero-pad +
   ``psum`` ZeRO-1 gather trick (partial-auto, where Shardy would
-  un-shard auto TP axes around the gather). Gated by
-  ``compat.SUPPORTS_PSUM_SCATTER`` / a full-manual caller, with the
-  older ``psum`` + local-slice emulation kept as the 0.4.x partial-auto
-  fallback (AllReduce wire, per-rank peel compute only); the
-  ``cfg.rs_wire`` knob forces either path. Overlap is honored on the
+  un-shard auto TP axes around the gather). The older ``psum`` +
+  local-slice emulation (AllReduce wire, per-rank peel compute only)
+  stays selectable with ``cfg.rs_wire="emulate"``. Overlap is honored on the
   native wire too: the stream scheduler stages per-chunk
   ``psum_scatter``/OR-Reduce-Scatter calls over chunks of whole
   per-rank bucket runs, and when the chunk grid aligns with the ZeRO-1
@@ -57,12 +55,10 @@ by the :mod:`repro.core.costmodel` controller and measures the per-bucket
 occupancy telemetry the controller feeds on.
 
 All strategies run *inside* the outer train-step ``shard_map`` (manual DP
-axes). On JAX with nested partial-manual support, packing/unpacking runs
-in a nested ``shard_map`` that takes the tensor-parallel axes manual too,
-so each device packs only its local parameter shards — no GSPMD
-resharding of gradients — while the codec and the DP collectives run at
-the outer level on the shard-local buckets. On 0.4.x the packed stream is
-the auto-sharded global view (same math; see ``repro.compat``).
+axes). Packing/unpacking runs in a nested ``shard_map`` that takes the
+tensor-parallel axes manual too, so each device packs only its local
+parameter shards — no GSPMD resharding of gradients — while the codec and
+the DP collectives run at the outer level on the shard-local buckets.
 
 Sparsification / error feedback are applied **per leaf** inside the pack
 stage — identical semantics (and bits) to the per-leaf path this replaced,
@@ -210,7 +206,18 @@ def sparsify_leaf(flat: jnp.ndarray, res: jnp.ndarray,
     return flat, new_res
 
 
-_sparsify_leaf = sparsify_leaf      # internal call sites / back-compat
+def pack_stream(plan: BucketPlan, g_tree, r_tree, cfg: CompressionConfig):
+    """The compressed strategies' pack stage: per-leaf sparsify/EF
+    (:func:`sparsify_leaf`), then bucket-pack. Returns the
+    ``(n_buckets, bucket_elems)`` f32 stream and the new residual tree."""
+    g_leaves = plan.treedef.flatten_up_to(g_tree)
+    r_leaves = plan.treedef.flatten_up_to(r_tree)
+    flats, new_res = [], []
+    for g, r in zip(g_leaves, r_leaves):
+        flat, nr = sparsify_leaf(g.reshape(-1).astype(jnp.float32), r, cfg)
+        flats.append(flat)
+        new_res.append(nr.reshape(r.shape))
+    return plan.pack_flat(flats), jax.tree.unflatten(plan.treedef, new_res)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -229,11 +236,9 @@ class CompressedAggregator:
     dp_axes: Tuple[str, ...]
     tp_axes: Tuple[str, ...] = ("model",)
     mean: bool = True
-    # The axis set the *caller's* shard_map takes manual. Only consulted
-    # by the reduce-scatter variant: on 0.4.x, axis_index in a
-    # partial-auto region lowers to a PartitionId the old partitioner
-    # rejects, so per-rank slicing needs either new JAX or a full-manual
-    # caller (the 0.4.x train step is full-manual; see compat).
+    # The axis set the *caller's* shard_map takes manual. A full-manual
+    # caller lets the reduce-scatter variant reassemble with a
+    # manual-axis all_gather (see _gather_chunks).
     outer_manual: Any = None
     # Per-leaf ZeRO-1 slice dims (from streams.zero_slice_dim, in
     # flattened-leaf order; None entries = unsliced leaves). Only the
@@ -464,31 +469,20 @@ class CompressedAggregator:
         dp_rank = linear_rank(self.dp_axes, dp_idx)
 
         manual = self._manual_set(spec_leaves)
-        nested = bool(manual) and compat.SUPPORTS_NESTED_SHARD_MAP
+        nested = bool(manual)
         if nested:
             local_shapes = [
                 _local_shape(g.shape, s, mesh)
                 for g, s in zip(leaves, spec_leaves)]
         else:
-            # Pure DP, or a JAX without nested partial-manual shard_map:
-            # pack the auto-sharded global view (same compress -> psum/OR
-            # -> recover math; nesting only avoids GSPMD resharding).
+            # Pure DP: the global view is the local one.
             local_shapes = [tuple(g.shape) for g in leaves]
         plan = make_bucket_plan(
             grads, cfg, shapes=jax.tree.unflatten(treedef, local_shapes))
 
         def pack_stage(g_tree, r_tree):
             """Shard-local: per-leaf sparsify/EF, then bucket-pack."""
-            g_leaves = plan.treedef.flatten_up_to(g_tree)
-            r_leaves = plan.treedef.flatten_up_to(r_tree)
-            flats, new_res = [], []
-            for g, r in zip(g_leaves, r_leaves):
-                flat, nr = _sparsify_leaf(
-                    g.reshape(-1).astype(jnp.float32), r, cfg)
-                flats.append(flat)
-                new_res.append(nr.reshape(r.shape))
-            return (plan.pack_flat(flats),
-                    jax.tree.unflatten(plan.treedef, new_res))
+            return pack_stream(plan, g_tree, r_tree, cfg)
 
         def unpack_stage(buckets):
             """Shard-local: bucket stream -> leaf pytree (mean)."""
@@ -531,10 +525,9 @@ class CompressedReduceScatterAggregator(CompressedAggregator):
 
     Phase I (pack/sparsify/encode) is identical to
     :class:`CompressedAggregator`. Phase II comes in two wire paths,
-    selected by ``cfg.rs_wire`` and the capability map:
+    selected by ``cfg.rs_wire``:
 
-    **Native** (``compat.SUPPORTS_PSUM_SCATTER``, or any JAX when the
-    caller's region is full-manual): the stacked sketch reduces with
+    **Native** (the default): the stacked sketch reduces with
     ``jax.lax.psum_scatter`` and the bitmap with the ring
     :func:`~repro.core.collectives.or_reduce_scatter`, both padded to
     whole per-rank chunks of ``nb_p/W`` buckets, so each rank *receives*
@@ -565,13 +558,10 @@ class CompressedReduceScatterAggregator(CompressedAggregator):
     zero outside (the train step reduces the grad-norm across ranks on
     that path; ``strategy_wire_bytes`` shows the saved gather wire).
 
-    **Emulated** (the 0.4.x partial-auto fallback, or
-    ``rs_wire="emulate"``): full ``psum`` + OR-AllReduce, then a local
-    slice — AllReduce wire cost, but still only 1/W of the peel compute
-    per rank. On 0.4.x partial-auto callers that did not declare
-    ``outer_manual`` it further degrades to all-ranks peeling (the rank
-    index cannot be lowered there). Overlap on this wire is plain
-    AllReduce chunking (the base class schedule).
+    **Emulated** (``rs_wire="emulate"``): full ``psum`` + OR-AllReduce,
+    then a local slice — AllReduce wire cost, but still only 1/W of the
+    peel compute per rank. Overlap on this wire is plain AllReduce
+    chunking (the base class schedule).
 
     All paths are bit-identical to :class:`CompressedAggregator` (modulo
     the gather-skip output contract above): the per-range peel runs the
@@ -583,25 +573,11 @@ class CompressedReduceScatterAggregator(CompressedAggregator):
 
     # -- geometry / capability helpers ---------------------------------
 
-    def _native_wire_possible(self) -> bool:
-        """The wire-selection predicate shared by :meth:`_native_wire`
-        and :meth:`_stream_plan` — one definition so the chunk grid can
-        never drift from the actual wire path taken."""
-        return self.cfg.rs_wire != "emulate" and (
-            compat.SUPPORTS_PSUM_SCATTER or self._full_manual())
-
     def _native_wire(self) -> bool:
-        """Whether phase II takes the psum_scatter/OR-RS wire path."""
-        if self.cfg.rs_wire == "emulate":
-            return False
-        ok = self._native_wire_possible()
-        if not ok and self.cfg.rs_wire == "native":
-            raise ValueError(
-                "rs_wire='native' requires a JAX with psum_scatter in "
-                "partial-auto manual regions (compat.SUPPORTS_PSUM_SCATTER) "
-                "or a caller whose shard_map takes every mesh axis manual "
-                "(pass outer_manual); use rs_wire='auto' to fall back")
-        return ok
+        """Whether phase II takes the psum_scatter/OR-RS wire path (the
+        one predicate :meth:`_stream_plan` also reads, so the chunk grid
+        can never drift from the wire path taken)."""
+        return self.cfg.rs_wire != "emulate"
 
     def _check_bitmap(self):
         if self.cfg.index != "bitmap":
@@ -633,15 +609,13 @@ class CompressedReduceScatterAggregator(CompressedAggregator):
         """Static: does the chunk grid align with the ZeRO-1 slices so
         the recovered-chunk all_gather can be skipped?
 
-        ``spec_leaves`` (DP-stripped specs): on a JAX with nested
-        shard_map, a leaf actually sharded on a non-DP axis makes the
-        packed stream a TP-*local* view while the ZeRO-1 slices are
-        global — the alignment math does not apply, keep the gather.
-        (On 0.4.x the packed stream is the auto-sharded global view, so
-        TP sharding does not disturb the stream coordinates.)"""
+        ``spec_leaves`` (DP-stripped specs): a leaf actually sharded on a
+        non-DP axis makes the packed stream a TP-*local* view while the
+        ZeRO-1 slices are global — the alignment math does not apply,
+        keep the gather."""
         if self.zero1_dims is None:
             return False
-        if compat.SUPPORTS_NESTED_SHARD_MAP and spec_leaves is not None \
+        if spec_leaves is not None \
                 and any(_spec_axes(s) for s in spec_leaves):
             return False
         return zero1_gather_skip(splan, plan, tuple(self.zero1_dims))
@@ -700,9 +674,8 @@ class CompressedReduceScatterAggregator(CompressedAggregator):
             sk, words = payload
             sk_loc = jax.lax.psum_scatter(
                 sk, tuple(self.dp_axes), scatter_dimension=0, tiled=True)
-            w_loc = or_reduce_scatter(
-                words, self.dp_axes, axis_indices=dp_idx,
-                use_ppermute=True if self._full_manual() else None)
+            w_loc = or_reduce_scatter(words, self.dp_axes,
+                                      axis_indices=dp_idx)
             return sk_loc, w_loc
         return red
 
@@ -727,13 +700,6 @@ class CompressedReduceScatterAggregator(CompressedAggregator):
                 block_offset=self.base_block + dp_rank * chunk_b * nbpb)
             return self._gather_chunks(rec_loc, plan, nb_p, chunk_elems,
                                        dp_rank)
-        full_manual = self._full_manual()
-        if not (compat.SUPPORTS_PARTIAL_AUTO_PPERMUTE or full_manual):
-            # 0.4.x partial-auto caller: the rank (axis_index) cannot be
-            # lowered — degrade to all-ranks peeling (same values, no
-            # per-rank compute scattering). See ``outer_manual``.
-            return CompressedAggregator._recover(
-                self, (sk, words), plan, comp, dp_idx, dp_rank)
         pad_b = nb_p - plan.n_buckets
         if pad_b:
             sk = jnp.pad(sk, ((0, pad_b * nbpb), (0, 0), (0, 0)))
@@ -823,9 +789,8 @@ class CompressedInNetworkAggregator(CompressedAggregator):
       :func:`repro.net.topology.tree_all_reduce` — integer add + OR,
       the only operations a programmable data plane has. Because
       integer adds are exact in any association order, the result is
-      bit-identical to the documented codec roundtrip (and to the psum
-      fallback on legs whose partitioner cannot run ppermute in the
-      calling region — same gating as the reduce-scatter wire).
+      bit-identical to the documented codec roundtrip (and to a flat
+      ``psum``).
     - ``cfg.wire_dtype == "f32"`` — an idealized float-capable
       aggregation tier (e.g. host-based aggregation servers): reuses
       the sketch-``psum`` + OR-AllReduce collectives, so it is
@@ -868,7 +833,6 @@ class CompressedInNetworkAggregator(CompressedAggregator):
             make_topology(cfg.topology, self.mesh, self.dp_axes)  # validate
             return super()._encode(buckets, plan, comp, dp_idx)
         topo = make_topology(cfg.topology, self.mesh, self.dp_axes)
-        use_pp = True if self._full_manual() else None
         wire = FixedPointWire(workers=self._dp_world())
         splan = self._stream_plan(plan)
         nbpb = splan.blocks_per_bucket
@@ -886,10 +850,10 @@ class CompressedInNetworkAggregator(CompressedAggregator):
             exp = jax.lax.pmax(wire.exponents_from_maxabs(bucket_max),
                                tuple(self.dp_axes))
             q = tree_all_reduce(wire.encode(sk_buckets, exp), topo, "add",
-                                axis_indices=dp_idx, use_ppermute=use_pp,
+                                axis_indices=dp_idx,
                                 window_slots=cfg.switch_slots)
             w = tree_all_reduce(words_buckets, topo, "or",
-                                axis_indices=dp_idx, use_ppermute=use_pp,
+                                axis_indices=dp_idx,
                                 window_slots=cfg.switch_slots)
             return q, w, exp
 
@@ -1002,10 +966,6 @@ class DenseAllToAllExchange:
     cfg: CompressionConfig
     mesh: Any
     ep_axes: Tuple[str, ...]
-    # The axis set the caller's shard_map takes manual — same role as
-    # CompressedAggregator.outer_manual: on 0.4.x the native ppermute
-    # lanes need a full-manual caller.
-    outer_manual: Any = None
 
     @property
     def workers(self) -> int:
@@ -1013,18 +973,6 @@ class DenseAllToAllExchange:
         for ax in self.ep_axes:
             W *= self.mesh.shape[ax]
         return W
-
-    def _full_manual(self) -> bool:
-        return (self.outer_manual is not None
-                and compat.full_manual_region(self.outer_manual, self.mesh))
-
-    def _use_ppermute(self) -> bool:
-        """Native permute lanes: single EP axis (ppermute takes one axis
-        name) and either new-JAX partial-auto ppermute or a full-manual
-        caller — the same compat gate as the RS wire."""
-        if len(self.ep_axes) != 1:
-            return False
-        return compat.SUPPORTS_PARTIAL_AUTO_PPERMUTE or self._full_manual()
 
     def _ep_idx(self):
         return {ax: jax.lax.axis_index(ax) for ax in self.ep_axes}
@@ -1044,7 +992,7 @@ class DenseAllToAllExchange:
         stack = self._pack(payload, plan)
         merged = alltoall_lane_sum(
             stack, tuple(self.ep_axes), axis_indices=self._ep_idx(),
-            use_ppermute=self._use_ppermute(), combine="add")
+            combine="add")
         return plan.unpack(merged)
 
 
@@ -1057,7 +1005,7 @@ class CompressedAllToAllExchange(DenseAllToAllExchange):
     producer pass (:meth:`HomomorphicCompressor.exchange_wire` — all
     ``W`` lanes in a single fused grid, chunk-major block ids), ships
     sketch + bitmap lanes over :func:`sketch_all_to_all` (W-1 ppermutes
-    native, psum-emulated under the RS wire's compat gate), and the
+    native, psum-emulated on a multi-axis EP), and the
     receiving rank recovers its merged lane in ONE consumer pass at the
     lane's global block offset — the PR 7 one-producer/one-consumer
     contract on the permute pattern.  The sketch add / bitmap OR on the
@@ -1093,7 +1041,6 @@ class CompressedAllToAllExchange(DenseAllToAllExchange):
         splan = make_alltoall_stream_plan(plan, cfg, lanes=W)
         ep_idx = self._ep_idx()
         rank = linear_rank(self.ep_axes, ep_idx)
-        use_pp = self._use_ppermute()
 
         def enc(i, chunk):                          # chunk: (W, cb, E)
             leaf, _ = comp.exchange_wire(
@@ -1103,8 +1050,7 @@ class CompressedAllToAllExchange(DenseAllToAllExchange):
         def red(wire_payload):
             sk, words = wire_payload
             return sketch_all_to_all(sk, words, tuple(self.ep_axes),
-                                     axis_indices=ep_idx,
-                                     use_ppermute=use_pp)
+                                     axis_indices=ep_idx)
 
         sks, ws = stream_schedule(splan.chunk_view(stack), enc, red)
         # sks (n_chunks, lane_blocks, rows, lanes) / ws (n_chunks, w):
@@ -1243,14 +1189,13 @@ def make_aggregator(name: str, cfg: CompressionConfig, mesh,
 
 
 def make_exchange(name: str, cfg: CompressionConfig, mesh,
-                  ep_axes: Sequence[str], outer_manual=None):
+                  ep_axes: Sequence[str]):
     """Build the named all-to-all exchange (see :data:`EXCHANGES`).
 
     Returns a differentiable callable for use *inside* a manual region
     where ``ep_axes`` are bound: ``(W, ...)`` lane pytree -> merged
     slice pytree (``sum_s payload_s[this_rank]``), with ``.workers`` /
     ``.ep_axes`` / ``.wire`` exposed for the caller's geometry checks.
-    ``outer_manual`` as in :func:`make_aggregator`.
     """
     if isinstance(ep_axes, str):
         ep_axes = (ep_axes,)
@@ -1260,5 +1205,4 @@ def make_exchange(name: str, cfg: CompressionConfig, mesh,
         raise ValueError(
             f"unknown exchange {name!r}; have {sorted(EXCHANGES)}")
     return _GradExchange(exchange=cls(
-        cfg=cfg, mesh=mesh, ep_axes=tuple(ep_axes),
-        outer_manual=None if outer_manual is None else tuple(outer_manual)))
+        cfg=cfg, mesh=mesh, ep_axes=tuple(ep_axes)))

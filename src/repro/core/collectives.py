@@ -45,7 +45,6 @@ from typing import Any, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from .config import CompressionConfig
 
 
@@ -73,7 +72,7 @@ def linear_rank(axis_names: Sequence[str],
     rank = jnp.int32(0)
     for ax in axis_names:
         idx = axis_indices[ax] if axis_indices else jax.lax.axis_index(ax)
-        rank = rank * compat.axis_size(ax) + idx
+        rank = rank * jax.lax.axis_size(ax) + idx
     return rank
 
 
@@ -87,7 +86,7 @@ def or_allreduce_ring(x: jnp.ndarray, axis_name: str,
     outer shard_map trips the Shardy verifier (re-binding), while plain
     ppermute/psum on outer axes are fine.
     """
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     if idx is None:
@@ -135,7 +134,7 @@ def or_reduce_scatter_ring(x: jnp.ndarray, axis_name: str,
     ``(idx+1) % n``, which only matters there because phase 2 regathers
     everything). ``idx``: see :func:`or_allreduce_ring`.
     """
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if x.shape[0] % n:
         raise ValueError(
             f"or_reduce_scatter: leading dim {x.shape[0]} not divisible "
@@ -159,7 +158,7 @@ def or_reduce_scatter_ring(x: jnp.ndarray, axis_name: str,
 
 def or_allreduce_doubling(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
     """Bitwise-OR AllReduce via recursive doubling (requires power-of-2)."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     if n & (n - 1):
@@ -185,8 +184,8 @@ def _or_allreduce_psum(x: jnp.ndarray, axis_names: Sequence[str],
 
     Unpacks each uint32 word into its 32 bits, psums the bit counts, and
     repacks ``count > 0``. 32x the wire volume of the native OR — this is
-    the compatibility path for JAX versions whose partitioner cannot run
-    ppermute over a manual axis while other mesh axes stay auto.
+    the path for callers that cannot use the ppermute ring (a multi-axis
+    all-to-all, or ``tree_all_reduce(use_ppermute=False)``).
 
     The unpack/psum runs in chunks of ``chunk_words`` leading-dim words
     (one psum per chunk, a static Python loop) so the int32 bit-unpack
@@ -257,11 +256,9 @@ def or_allreduce(x: jnp.ndarray, axis_names: Sequence[str],
     if isinstance(axis_names, str):
         axis_names = (axis_names,)
     _check_axis_indices(axis_names, axis_indices)
-    if not compat.SUPPORTS_PARTIAL_AUTO_PPERMUTE:
-        return _or_allreduce_psum(x, axis_names)
     payload_bytes = x.size * x.dtype.itemsize
     for ax in reversed(tuple(axis_names)):
-        if _use_ring(payload_bytes, compat.axis_size(ax), ring_threshold):
+        if _use_ring(payload_bytes, jax.lax.axis_size(ax), ring_threshold):
             idx = axis_indices[ax] if axis_indices else None
             x = or_allreduce_ring(x, ax, idx=idx)
         else:
@@ -270,8 +267,7 @@ def or_allreduce(x: jnp.ndarray, axis_names: Sequence[str],
 
 
 def or_reduce_scatter(x: jnp.ndarray, axis_names: Sequence[str],
-                      axis_indices: Optional[dict] = None,
-                      use_ppermute: Optional[bool] = None) -> jnp.ndarray:
+                      axis_indices: Optional[dict] = None) -> jnp.ndarray:
     """Hierarchical bitwise-OR Reduce-Scatter over (manual) mesh axes.
 
     Each rank receives only its own fully OR-reduced ``1/W`` chunk of
@@ -283,14 +279,6 @@ def or_reduce_scatter(x: jnp.ndarray, axis_names: Sequence[str],
     axis a sub-chunk of it. (The AllReduce driver reduces innermost-first
     instead; order is irrelevant there because everyone ends with
     everything.)
-
-    ``use_ppermute``: force (True) or forbid (False) the ppermute ring.
-    Default ``None`` follows ``compat.SUPPORTS_PARTIAL_AUTO_PPERMUTE``;
-    callers inside *full-manual* regions on 0.4.x should pass True (the
-    ring is supported there — see compat.full_manual_region). When the
-    ring is unavailable the result is emulated as a psum-based
-    OR-AllReduce plus a local chunk slice: correct, but it forfeits the
-    wire win (compat path only).
     """
     if isinstance(axis_names, str):
         axis_names = (axis_names,)
@@ -298,18 +286,11 @@ def or_reduce_scatter(x: jnp.ndarray, axis_names: Sequence[str],
     _check_axis_indices(axis_names, axis_indices)
     W = 1
     for ax in axis_names:
-        W *= compat.axis_size(ax)
+        W *= jax.lax.axis_size(ax)
     if x.shape[0] % W:
         raise ValueError(
             f"or_reduce_scatter: leading dim {x.shape[0]} not divisible "
             f"by the total axis size {W}")
-    if use_ppermute is None:
-        use_ppermute = compat.SUPPORTS_PARTIAL_AUTO_PPERMUTE
-    if not use_ppermute:
-        full = _or_allreduce_psum(x, axis_names)
-        rank = linear_rank(axis_names, axis_indices)
-        return jax.lax.dynamic_slice_in_dim(
-            full, rank * (x.shape[0] // W), x.shape[0] // W, axis=0)
     for ax in axis_names:
         idx = axis_indices[ax] if axis_indices else None
         x = or_reduce_scatter_ring(x, ax, idx=idx)
@@ -342,7 +323,7 @@ def gather_chunk_slices(local: jnp.ndarray, axis_names: Sequence[str],
     _check_axis_indices(axis_names, axis_indices)
     W = 1
     for ax in axis_names:
-        W *= compat.axis_size(ax)
+        W *= jax.lax.axis_size(ax)
     if W == 1:
         return local
     n_chunks, s = local.shape[0], local.shape[1]
@@ -369,7 +350,6 @@ def gather_chunk_slices(local: jnp.ndarray, axis_names: Sequence[str],
 
 def alltoall_lane_sum(x: jnp.ndarray, axis_names: Sequence[str],
                        axis_indices: Optional[dict] = None,
-                       use_ppermute: Optional[bool] = None,
                        combine: str = "add") -> jnp.ndarray:
     """Merge stacked all-to-all lanes: rank ``r`` receives
     ``combine_s x_s[r]`` over all source ranks ``s``.
@@ -386,11 +366,10 @@ def alltoall_lane_sum(x: jnp.ndarray, axis_names: Sequence[str],
     all-to-all wire model in ``CompressionConfig.strategy_wire_bytes``).
     Single manual axis only (ppermute takes one axis name).
 
-    Emulation (0.4.x partial-auto, or multi-axis EP): reduce the whole
-    ``(W, ...)`` stack — psum for ``add``, the psum-based OR for ``or``
-    — then slice this rank's lane.  Correct, but ships the ring
-    AllReduce volume (and 32x on the bitmap), the same compat cost as
-    :func:`or_reduce_scatter`'s fallback.
+    Emulation (multi-axis EP): reduce the whole ``(W, ...)`` stack —
+    psum for ``add``, the psum-based OR for ``or`` — then slice this
+    rank's lane.  Correct, but ships the ring AllReduce volume (and 32x
+    on the bitmap).
     """
     if isinstance(axis_names, str):
         axis_names = (axis_names,)
@@ -400,16 +379,14 @@ def alltoall_lane_sum(x: jnp.ndarray, axis_names: Sequence[str],
         raise ValueError(f"combine must be 'add' or 'or', got {combine!r}")
     W = 1
     for ax in axis_names:
-        W *= compat.axis_size(ax)
+        W *= jax.lax.axis_size(ax)
     if x.shape[0] != W:
         raise ValueError(
             f"all-to-all payload has {x.shape[0]} lanes but the axis "
             f"tuple {tuple(axis_names)} has {W} ranks")
     if W == 1:
         return x[0]
-    if use_ppermute is None:
-        use_ppermute = compat.SUPPORTS_PARTIAL_AUTO_PPERMUTE
-    if use_ppermute and len(axis_names) == 1:
+    if len(axis_names) == 1:
         ax = axis_names[0]
         idx = axis_indices[ax] if axis_indices else jax.lax.axis_index(ax)
         out = jax.lax.dynamic_index_in_dim(x, idx, 0, keepdims=False)
@@ -430,8 +407,7 @@ def alltoall_lane_sum(x: jnp.ndarray, axis_names: Sequence[str],
 
 def sketch_all_to_all(sketches: jnp.ndarray, words: jnp.ndarray,
                       axis_names: Sequence[str],
-                      axis_indices: Optional[dict] = None,
-                      use_ppermute: Optional[bool] = None):
+                      axis_indices: Optional[dict] = None):
     """Compressed expert-parallel all-to-all: ship per-destination sketch
     lanes over the permute wire and merge them homomorphically at the
     receiving rank (PR 8).
@@ -447,15 +423,13 @@ def sketch_all_to_all(sketches: jnp.ndarray, words: jnp.ndarray,
     barrier and no full gather, the ScaleCom/THC point that the
     homomorphic combine must land at the receiving expert.
 
-    ``use_ppermute``: as in :func:`or_reduce_scatter` — ``None`` follows
-    ``compat.SUPPORTS_PARTIAL_AUTO_PPERMUTE``; full-manual callers on
-    0.4.x should pass True.  The native path needs a single manual axis;
-    multi-axis EP always takes the psum-emulation fallback.
+    The native path needs a single manual axis; multi-axis EP takes the
+    psum-emulation fallback (see :func:`alltoall_lane_sum`).
     """
     sk = alltoall_lane_sum(sketches, axis_names, axis_indices=axis_indices,
-                            use_ppermute=use_ppermute, combine="add")
+                            combine="add")
     wd = alltoall_lane_sum(words, axis_names, axis_indices=axis_indices,
-                            use_ppermute=use_ppermute, combine="or")
+                            combine="or")
     return sk, wd
 
 
@@ -470,7 +444,7 @@ def dense_all_reduce(grads: Any, axis_names: Sequence[str],
         axis_names = (axis_names,)
     w = 1
     for ax in axis_names:
-        w *= compat.axis_size(ax)
+        w *= jax.lax.axis_size(ax)
 
     def red(g):
         s = jax.lax.psum(g.astype(acc_dtype), tuple(axis_names))
@@ -530,12 +504,9 @@ def compressed_all_reduce(grads: Any, agg_state: AggregationState,
     where ``dp_axes`` are already manual.
 
     ``outer_manual``: the axis set that enclosing shard_map takes manual
-    — forwarded to the aggregator, where it decides whether the
-    reduce-scatter strategy may slice/scatter per rank on 0.4.x (a fully
-    manual caller supports the native wire path and per-rank peeling even
-    without SUPPORTS_PSUM_SCATTER / partial-auto ppermute). Omitting it
-    never affects correctness, but silently degrades ``reduce_scatter``
-    to all-ranks peeling over the emulated wire on 0.4.x.
+    — forwarded to the aggregator, where a full-manual caller lets the
+    reduce-scatter strategy reassemble with a manual-axis all_gather.
+    Omitting it never affects correctness.
 
     Returns: (aggregated grads pytree, new AggregationState)
     """

@@ -51,7 +51,7 @@ class RecoveryStats(NamedTuple):
     rounds: jnp.ndarray       # peeling rounds used
 
 
-def _chunked_map(fn, nb: int, chunk: int, *arrays):
+def chunked_map(fn, nb: int, chunk: int, *arrays):
     """lax.map ``fn`` over blocks in chunks; pads nb to a chunk multiple.
 
     ``arrays`` all have leading dim nb. Padding blocks are all-zero, which
@@ -105,7 +105,7 @@ class HomomorphicCompressor:
             def enc(ids_c, xb_c):
                 return ops.encode_pack_quantize(xb_c, ids_c, self.cfg)
 
-            sketch, words2d, maxabs = _chunked_map(
+            sketch, words2d, maxabs = chunked_map(
                 enc, plan.nb, self.cfg.chunk_blocks, ids, xb)
             return (CompressedLeaf(sketch=sketch,
                                    index_words=words2d.reshape(-1)),
@@ -114,7 +114,7 @@ class HomomorphicCompressor:
         def enc(ids_c, xb_c):
             return ops.sketch_encode(xb_c, ids_c, self.cfg)
 
-        sketch = _chunked_map(enc, plan.nb, self.cfg.chunk_blocks, ids, xb)
+        sketch = chunked_map(enc, plan.nb, self.cfg.chunk_blocks, ids, xb)
         if self.cfg.index == "bitmap":
             words = index_lib.pack_bits(index_lib.bitmap_build(xb))
         else:
@@ -203,7 +203,7 @@ class HomomorphicCompressor:
                         sk_c, w_c, ids_c, self.cfg,
                         exponents=e_c, mantissa_bits=mbits)
 
-                values, residual = _chunked_map(
+                values, residual = chunked_map(
                     rec, plan.nb, self.cfg.chunk_blocks,
                     ids, comp.sketch, words2d,
                     jnp.asarray(exps, jnp.int32))
@@ -211,7 +211,7 @@ class HomomorphicCompressor:
                 def rec(ids_c, sk_c, w_c):
                     return ops.dequant_peel_unpack(sk_c, w_c, ids_c, self.cfg)
 
-                values, residual = _chunked_map(
+                values, residual = chunked_map(
                     rec, plan.nb, self.cfg.chunk_blocks,
                     ids, comp.sketch, words2d)
             nnz = jnp.sum(jax.lax.population_count(comp.index_words)
@@ -232,7 +232,7 @@ class HomomorphicCompressor:
             def rec(ids_c, sk_c, bits_c):
                 return ops.sketch_peel(sk_c, bits_c, ids_c, self.cfg)
 
-            values, residual = _chunked_map(
+            values, residual = chunked_map(
                 rec, plan.nb, self.cfg.chunk_blocks, ids, sketch, bits)
             nnz = jnp.sum(bits)
         x = from_blocks(values, plan, shape)
@@ -256,7 +256,7 @@ class HomomorphicCompressor:
         def est(ids_c, sk_c):
             return ops.sketch_estimate(sk_c, ids_c, self.cfg)
 
-        values = _chunked_map(est, plan.nb, self.cfg.chunk_blocks, ids, comp.sketch)
+        values = chunked_map(est, plan.nb, self.cfg.chunk_blocks, ids, comp.sketch)
         if self.cfg.index == "bitmap":
             bits = index_lib.unpack_bits(
                 comp.index_words, (plan.nb, plan.group, plan.lanes))

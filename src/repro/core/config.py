@@ -58,9 +58,10 @@ class CompressionConfig:
     use_pallas: str = "auto"     # "never" | "always" | "auto"
     encode_block_tile: int = 8   # sketch blocks per encode-kernel grid
                                  # cell (VMEM-bounded; see sketch_encode)
-    peel_block_tile: int = 4     # sketch blocks per peel-kernel grid cell
-                                 # (smaller than encode: the peel loop
-                                 # keeps y/b/d/x tiles live across rounds)
+    peel_block_tile: int = 8     # sketch blocks per peel-kernel grid cell
+                                 # (the peel loop runs block by block in
+                                 # VMEM scratch, so its state does not
+                                 # grow with the tile)
     bucket_bytes: int = 4 << 20  # target f32 bytes per aggregation bucket
                                  # (rounded to block/word alignment; see
                                  # bucketing.BucketPlan)
@@ -82,14 +83,10 @@ class CompressionConfig:
                                  # count is valid on the AllReduce wire
                                  # (non-divisible grids zero-pad).
     rs_wire: str = "auto"        # reduce-scatter strategy wire path:
-                                 # "auto"    — native psum_scatter + OR-RS
-                                 #             when the JAX leg / region
-                                 #             supports it, else the
-                                 #             psum+slice emulation;
-                                 # "native"  — require native (raise if
-                                 #             unsupported);
-                                 # "emulate" — force the emulation (for
-                                 #             parity tests / benchmarks)
+                                 # "auto" / "native" — psum_scatter +
+                                 #             OR-RS (every region);
+                                 # "emulate" — the psum+slice emulation
+                                 #             (parity tests / benchmarks)
     wire_dtype: str = "f32"      # compressed_innet sketch wire (PR 4):
                                  # "f32"   — idealized float-capable
                                  #           aggregation tier (bit-parity
@@ -332,10 +329,9 @@ class CompressionConfig:
         the native RS arm. (With fewer buckets than ranks that chunk
         padding can erase the native win entirely: one bucket over two
         ranks scatters nothing.) Other caveats: the numbers model the
-        *native* collectives; on a 0.4.x partial-auto leg the
-        OR-AllReduce is psum-emulated at 32x the bitmap's wire volume
-        (``or_emulated_factor`` is provided to scale index traffic for
-        that leg).
+        *native* collectives; where the OR is psum-emulated (the
+        multi-axis all-to-all) it ships 32x the bitmap's wire volume
+        (``or_emulated_factor`` scales index traffic for that path).
 
         ``compressed_rs``'s native path reports the recovered-chunk
         all_gather separately: ``link_bytes_with_gather`` counts it,
@@ -434,7 +430,7 @@ class CompressionConfig:
         # (W-1)/W x the stacked payload each way, the all-to-all analogue
         # of the reduce-scatter factor. The compressed wire ships the
         # sketch+bitmap of each lane instead of the raw slice; the
-        # psum-emulation fallback (0.4.x partial-auto, multi-axis EP)
+        # psum-emulation fallback (multi-axis EP)
         # reduces the whole stack at ring AllReduce volume
         # (``link_bytes_emulated``; bitmap additionally at
         # ``or_emulated_factor``).

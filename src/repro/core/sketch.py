@@ -70,12 +70,30 @@ def roll_from_sketch(y: jnp.ndarray, rot: jnp.ndarray, lanes: int) -> jnp.ndarra
 # Scatter / gather between batches and sketch rows
 # ----------------------------------------------------------------------
 
+def row_members(rows_tbl: np.ndarray, rows: int):
+    """Static scatter plan: for each sketch row ``r``, its hash ``j`` and
+    the batches ``i`` with ``h_j(i) == r``, ascending. The row tables are
+    3-partite, so every row is fed by exactly one hash."""
+    per = rows // 3
+    return [(r // per, [int(i) for i in np.nonzero(rows_tbl[:, r // per] == r)[0]])
+            for r in range(rows)]
+
+
 def scatter_rows(contrib: jnp.ndarray, rows_tbl: np.ndarray, rows: int) -> jnp.ndarray:
-    """contrib (nb,G,3,c) -> sketch (nb,rows,c) via scatter-add on h_j(i)."""
-    nb, g, _, c = contrib.shape
-    flat = contrib.reshape(nb, g * 3, c)
-    h_flat = jnp.asarray(rows_tbl.reshape(-1), dtype=jnp.int32)
-    return jnp.zeros((nb, rows, c), contrib.dtype).at[:, h_flat, :].add(flat)
+    """contrib (nb,G,3,c) -> sketch (nb,rows,c), summed on h_j(i).
+
+    Each row is a left-to-right chain of adds over its batches in
+    ascending order (:func:`row_members`) — the same order the Pallas
+    kernels add in, so float sketches and peels agree bit for bit on any
+    backend (a scatter-add leaves the order to the compiler)."""
+    nb, _, _, c = contrib.shape
+    out = []
+    for j, members in row_members(rows_tbl, rows):
+        acc = jnp.zeros((nb, c), contrib.dtype)
+        for i in members:
+            acc = acc + contrib[:, i, j, :]
+        out.append(acc)
+    return jnp.stack(out, axis=1)
 
 
 def gather_rows(sketch: jnp.ndarray, rows_tbl: np.ndarray) -> jnp.ndarray:

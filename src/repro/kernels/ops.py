@@ -62,14 +62,13 @@ def sketch_peel(sketch: jnp.ndarray, bits: jnp.ndarray,
 def fused_wire_supported(cfg: CompressionConfig) -> bool:
     """Whether the fused wire-codec ops cover this geometry.
 
-    The fused producer packs the bitmap *per block*, so the pack-word
-    boundary must coincide with the block boundary (``block_elems %
-    32 == 0`` — always true for default geometries, where
-    ``bucket_quantum = lcm(block_elems, 32)``), and only the exact
+    The fused kernels pack the bitmap *per batch row*, so a pack word
+    must never straddle two rows (``lanes % 32 == 0`` — true for the
+    default 512 and every lane-aligned TPU geometry), and only the exact
     bitmap index is pack-fusable (Bloom needs a global scatter over all
     coordinates, inherently cross-block).
     """
-    return cfg.index == "bitmap" and cfg.block_elems % 32 == 0
+    return cfg.index == "bitmap" and cfg.lanes % 32 == 0
 
 
 def encode_pack_quantize(xb: jnp.ndarray, block_ids: jnp.ndarray,
@@ -92,7 +91,7 @@ def encode_pack_quantize(xb: jnp.ndarray, block_ids: jnp.ndarray,
     if not fused_wire_supported(cfg):
         raise ValueError(
             f"fused wire codec unsupported for index={cfg.index!r}, "
-            f"block_elems={cfg.block_elems} (need bitmap and %32==0)")
+            f"lanes={cfg.lanes} (need bitmap and lanes % 32 == 0)")
     if _want_pallas(cfg):
         return encode_pack_quantize_pallas(
             xb, block_ids, cfg, exponents=exponents,
@@ -117,7 +116,7 @@ def dequant_peel_unpack(sketch: jnp.ndarray, words: jnp.ndarray,
     if not fused_wire_supported(cfg):
         raise ValueError(
             f"fused wire codec unsupported for index={cfg.index!r}, "
-            f"block_elems={cfg.block_elems} (need bitmap and %32==0)")
+            f"lanes={cfg.lanes} (need bitmap and lanes % 32 == 0)")
     if _want_pallas(cfg):
         return dequant_peel_unpack_pallas(
             sketch, words, block_ids, cfg, exponents=exponents,

@@ -1,155 +1,205 @@
 """Pallas TPU kernel: parallel-peeling recovery (paper §3.2).
 
 Grid = one cell per *tile* of ``peel_block_tile`` sketch blocks (the same
-multi-block grid-cell tiling as the encode kernel); the whole peeling
-loop for a tile runs *inside* the kernel, so the sketch tile, degree tile
-and index tile stay VMEM-resident across rounds — the TPU translation of
-the paper's §3.4 cache-locality argument (their GPU version re-reads
+tiling as the encode kernel); the whole peeling loop for each block runs
+*inside* the kernel on VMEM-resident scratch planes — the TPU translation
+of the paper's §3.4 cache-locality argument (their GPU version re-reads
 global memory per round; here HBM sees exactly one read of [Y, B] and one
-write of X). Batching blocks per cell amortises the per-cell hash-plan
-setup and keeps the VPU busy on short rows.
+write of X).
 
-The round count is a static unroll bound: with block-local sketches the
-paper's peeling finishes in O(1) rounds, so a fixed `cfg.rounds` loses
-nothing while keeping the kernel control-flow-free for the TPU scalar
-unit. Rounds after the fixpoint are cheap no-ops (all-false peel masks).
+The round count is a static bound: with block-local sketches the paper's
+peeling finishes in O(1) rounds, so a fixed ``cfg.rounds`` loses nothing
+while keeping the kernel free of data-dependent control flow. Rounds
+after the fixpoint are exact no-ops (all-false peel masks).
 
-Per-round math is identical to :mod:`repro.core.peeling` (the oracle):
-degree gather -> singleton test -> exact value extraction -> subtract.
+Per-round math is that of :mod:`repro.core.peeling` (the oracle), in
+forms Mosaic lowers and in the oracle's order of float operations, so
+the results are bit-identical:
 
-VMEM budget per cell (defaults B=4, G=60, c=512, rows=6): y 4*6*512*4 =
-48 KiB, b/d/x tiles 3 x 4*60*512*(1|4) ≈ 1.1 MiB, the (B, G, 3, c)
-rotation gathers 2.8 MiB — comfortably under ~16 MiB/core with double
-buffering (the peel loop keeps more state live than encode, hence the
-smaller default tile).
+- degree/value gather at ``h_j(i)``: a select among the (static) rows of
+  hash ``j`` — the row tables are 3-partite, so hash ``j`` only ever
+  reads its own ``rows/3`` rows;
+- lane rotations: the barrel shifter of
+  :func:`repro.kernels.sketch_encode.roll_rows`;
+- first peelable hash (the oracle's argmax over 3): compare and select;
+- scatter back onto the rows: chains of row adds in the static order of
+  :func:`repro.core.sketch.row_members`.
+
+VMEM per cell at the defaults (B=8, G=60, c=512, rows=6): sketch tile
+96 KiB, int8 bits tile and int8 residual tile 240 KiB each, values out
+960 KiB, rotations 256 KiB as laid out — each double-buffered — plus six
+(G, c) / (rows, c) scratch planes, about 0.5 MiB.
 """
 
 from __future__ import annotations
 
 import functools
 
-import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro import compat
 from repro.core.config import CompressionConfig
-from repro.core import hashing
-from .sketch_encode import _rotations_for_block
+from repro.core.sketch import plan_tables, row_members
+from .sketch_encode import (add_rows, block_rotations, pad_blocks,
+                            roll_rows, tile_geometry)
 
 
-def peel_tile(ids, rows_flat, signs, y, b, cfg: CompressionConfig):
-    """The in-kernel peel math for one tile: (B,) ids + (G*3,) row table
-    + (G, 3) signs + (B, rows, c) sketch + (B, G, c) bool bits ->
-    (values (B, G, c) f32, residual (B, G, c) bool).
+def _gather_rows(ref, hcol, j: int, cfg: CompressionConfig):
+    """(rows, c) ``ref`` -> (G, c): row ``h_j(i)`` for every batch ``i``,
+    selected among hash ``j``'s rows by the (G, 1) row-table column."""
+    per = cfg.rows // 3
+    shape = (cfg.group, cfg.lanes)
+    rows = list(range(j * per, (j + 1) * per))
+    out = jnp.broadcast_to(ref[pl.ds(rows[-1], 1), :], shape)
+    for r in reversed(rows[:-1]):
+        out = jnp.where(hcol == r,
+                        jnp.broadcast_to(ref[pl.ds(r, 1), :], shape), out)
+    return out
 
-    Shared by :func:`_peel_kernel` and the fused wire-codec kernel in
-    :mod:`repro.kernels.sketch_wire` — ONE implementation of the peeling
-    loop, so the fused consumer can never drift from the plain peel.
-    """
-    B = y.shape[0]                        # blocks per grid cell (tile)
-    G, R, c = cfg.group, cfg.rows, cfg.lanes
-    rot = _rotations_for_block(ids, G, c, cfg.seed)                   # (B,G,3)
-    sg = signs[None, :, :, None]                                      # (1,G,3,1)
 
-    lane = jnp.arange(c, dtype=jnp.int32)
-    fwd_idx = (lane[None, None, None, :] - rot[..., None]) % c        # to sketch
-    bwd_idx = (lane[None, None, None, :] + rot[..., None]) % c        # roll back
+def _scatter(dst_ref, plane_ref, members, signs, j: int, zero,
+             subtract: bool):
+    """Every row ``r`` of hash ``j``: ``dst[r] = s`` (or ``dst[r] - s``
+    with ``subtract``) for ``s = sum_i sign * plane[i]`` —
+    :func:`repro.core.sketch.scatter_rows` of that row."""
+    for r, (jr, mem) in enumerate(members):
+        if jr == j:
+            acc = add_rows(plane_ref, mem, signs, zero)
+            if subtract:
+                acc = dst_ref[pl.ds(r, 1), :] - acc
+            dst_ref[pl.ds(r, 1), :] = acc
 
-    def roll_fwd(v):   # (B,G,c) -> (B,G,3,c)
-        vb = jnp.broadcast_to(v[:, :, None, :], (B, G, 3, c))
-        return jnp.take_along_axis(vb, fwd_idx, axis=-1)
 
-    def roll_bwd(v):   # (B,G,3,c) -> (B,G,3,c)
-        return jnp.take_along_axis(v, bwd_idx, axis=-1)
+def peel_block(y, bits, rot, tbl_ref, sgn_ref, cfg: CompressionConfig,
+               scratch):
+    """Peel one block: ``y`` (rows, c) f32 sketch, ``bits`` (G, c) int32
+    0/1 index, ``rot`` (G, 3) int32 rotations -> (values (G, c) f32,
+    residual (G, c) int32 0/1).
 
-    def scatter(contrib):  # (B,G,3,c) -> (B,R,c)
-        flat = contrib.reshape(B, G * 3, c)
-        return jnp.zeros((B, R, c), contrib.dtype).at[:, rows_flat].add(flat)
+    ``tbl_ref`` / ``sgn_ref``: the (G, 3) row table (int32) and signs
+    (f32). ``scratch``: the (y, d, b, x, f32 plane, int32 plane) VMEM
+    scratch refs, (rows, c) for y/d and (G, c) for the rest."""
+    y_s, d_s, b_s, x_s, pf, pi = scratch
+    G, c = cfg.group, cfg.lanes
+    rows_tbl, signs = plan_tables(cfg)
+    members = row_members(rows_tbl, cfg.rows)
+    zf = jnp.zeros((1, c), jnp.float32)
+    zi = jnp.zeros((1, c), jnp.int32)
+    cols = [(tbl_ref[:, j:j + 1], sgn_ref[:, j:j + 1], rot[:, j:j + 1])
+            for j in range(3)]
 
-    def gather(t):     # (B,R,c) -> (B,G,3,c)
-        return jnp.take(t, rows_flat, axis=1).reshape(B, G, 3, c)
+    # Initial degrees: scatter the rotated index bits.
+    y_s[...] = y
+    b_s[...] = bits
+    x_s[...] = jnp.zeros((G, c), jnp.float32)
+    for j, (_, _, rcol) in enumerate(cols):
+        pi[...] = roll_rows(bits, rcol, c)
+        _scatter(d_s, pi, members, None, j, zi, subtract=False)
 
-    y = y.astype(jnp.float32)                                         # (B,R,c)
-    d = scatter(roll_fwd(b.astype(jnp.int32)))                        # (B,R,c)
-    x = jnp.zeros((B, G, c), jnp.float32)
+    def at(j):
+        """(degree, signed value) of every batch's cell for hash j."""
+        hcol, scol, rcol = cols[j]
+        d_at = roll_rows(_gather_rows(d_s, hcol, j, cfg), rcol, c,
+                         inverse=True)
+        v_at = roll_rows(_gather_rows(y_s, hcol, j, cfg), rcol, c,
+                         inverse=True) * scol
+        return d_at, v_at
 
-    def round_body(_, state):
-        y, b, d, x = state
-        d_at = roll_bwd(gather(d))
-        v_at = roll_bwd(gather(y)) * sg
-        peelable = (d_at == 1) & b[:, :, None, :]
-        any_peel = jnp.any(peelable, axis=2)
-        jstar = jnp.argmax(peelable, axis=2)
-        val = jnp.take_along_axis(v_at, jstar[:, :, None, :], axis=2)[:, :, 0, :]
+    def round_body(_, carry):
+        live = b_s[...] != 0
+        (d0, v0), (d1, v1), (d2, v2) = at(0), at(1), at(2)
+        p0 = (d0 == 1) & live
+        p1 = (d1 == 1) & live
+        p2 = (d2 == 1) & live
+        any_peel = p0 | p1 | p2
+        val = jnp.where(p0, v0, jnp.where(p1, v1, v2))
         val = jnp.where(any_peel, val, 0.0)
-        y = y - scatter(roll_fwd(val) * sg)
-        d = d - scatter(roll_fwd(any_peel.astype(jnp.int32)))
-        b = b & ~any_peel
-        x = x + val
-        return y, b, d, x
+        ones = jnp.where(any_peel, 1, 0).astype(jnp.int32)
+        for j, (_, _, rcol) in enumerate(cols):
+            pf[...] = roll_rows(val, rcol, c)
+            _scatter(y_s, pf, members, signs[:, j], j, zf, subtract=True)
+            pi[...] = roll_rows(ones, rcol, c)
+            _scatter(d_s, pi, members, None, j, zi, subtract=True)
+        b_s[...] = jnp.where(any_peel, 0, b_s[...])
+        x_s[...] = x_s[...] + val
+        return carry
 
-    y, b, d, x = jax.lax.fori_loop(0, cfg.rounds, round_body, (y, b, d, x))
+    jax.lax.fori_loop(0, cfg.rounds, round_body, 0)
 
     # Residue -> unbiased median-of-3 estimate (paper footnote 5).
-    est = roll_bwd(gather(y)) * sg
-    v0, v1, v2 = est[:, :, 0], est[:, :, 1], est[:, :, 2]
+    v0, v1, v2 = at(0)[1], at(1)[1], at(2)[1]
     med = (v0 + v1 + v2
            - jnp.maximum(jnp.maximum(v0, v1), v2)
            - jnp.minimum(jnp.minimum(v0, v1), v2))
-    return x + jnp.where(b, med, 0.0), b
+    b = b_s[...]
+    return x_s[...] + jnp.where(b != 0, med, 0.0), b
 
 
-def _peel_kernel(ids_ref, rows_ref, signs_ref, y_ref, b_ref, xo_ref, ro_ref,
-                 *, cfg: CompressionConfig):
-    ids = ids_ref[...][:, 0]                                          # (B,)
-    values, residual = peel_tile(ids, rows_ref[:, 0], signs_ref[...],
-                                 y_ref[...], b_ref[...] != 0, cfg)
-    xo_ref[...] = values
-    ro_ref[...] = residual.astype(jnp.int8)
+def peel_scratch(cfg: CompressionConfig):
+    """The VMEM scratch planes :func:`peel_block` works in."""
+    G, c, R = cfg.group, cfg.lanes, cfg.rows
+    return [pltpu.VMEM((R, c), jnp.float32), pltpu.VMEM((R, c), jnp.int32),
+            pltpu.VMEM((G, c), jnp.int32), pltpu.VMEM((G, c), jnp.float32),
+            pltpu.VMEM((G, c), jnp.float32), pltpu.VMEM((G, c), jnp.int32)]
 
 
+def plan_operands(cfg: CompressionConfig):
+    """(row table int32 (G, 3), signs f32 (G, 3)) kernel operands and
+    their whole-array block specs."""
+    rows_tbl, signs = plan_tables(cfg)
+    spec = pl.BlockSpec((cfg.group, 3), lambda i: (0, 0))
+    return ([jnp.asarray(rows_tbl, jnp.int32), jnp.asarray(signs)],
+            [spec, spec])
+
+
+def _peel_kernel(rot_ref, tbl_ref, sgn_ref, y_ref, b_ref, xo_ref, ro_ref,
+                 *scratch, cfg: CompressionConfig):
+    def body(b, carry):
+        bits = jnp.minimum(jnp.abs(b_ref[b].astype(jnp.int32)), 1)
+        values, residual = peel_block(y_ref[b].astype(jnp.float32), bits,
+                                      rot_ref[b], tbl_ref, sgn_ref, cfg,
+                                      scratch)
+        xo_ref[b] = values
+        ro_ref[b] = residual.astype(jnp.int8)
+        return carry
+
+    jax.lax.fori_loop(0, y_ref.shape[0], body, 0)
+
+
+@compat.per_device
 def sketch_peel_pallas(sketch: jnp.ndarray, bits: jnp.ndarray,
                        block_ids: jnp.ndarray, cfg: CompressionConfig,
                        interpret: bool = True):
     """(nb,rows,c) sketch + (nb,G,c) bits -> (values (nb,G,c) f32,
     residual (nb,G,c) int8)."""
     nb = sketch.shape[0]
-    tile = max(1, min(cfg.peel_block_tile, nb))
-    padded = -(-nb // tile) * tile
-    if padded != nb:
-        # Zero sketch blocks with empty indexes peel to exact zeros;
-        # their (arbitrary) ids only seed rotations of zeros. Sliced
-        # back off below.
-        sketch = jnp.pad(sketch, ((0, padded - nb), (0, 0), (0, 0)))
-        bits = jnp.pad(bits, ((0, padded - nb), (0, 0), (0, 0)))
-        block_ids = jnp.pad(block_ids, (0, padded - nb))
-    g3 = cfg.group * 3
-    rows_tbl = jnp.asarray(
-        hashing.batch_rows(cfg.group, cfg.rows, cfg.seed).reshape(g3, 1))
-    signs = jnp.asarray(hashing.batch_signs(cfg.group, cfg.seed))
-    kern = functools.partial(_peel_kernel, cfg=cfg)
-    ids2d = block_ids.reshape(padded, 1).astype(jnp.int32)
+    tile, padded = tile_geometry(nb, cfg.peel_block_tile)
+    G, c, R = cfg.group, cfg.lanes, cfg.rows
+    # Zero sketch blocks with empty indexes peel to exact zeros; sliced
+    # back off below.
+    rot = pad_blocks(block_rotations(block_ids, cfg), padded)
+    sketch = pad_blocks(sketch, padded)
+    bits = pad_blocks(bits.astype(jnp.int8), padded)
+    plan, plan_specs = plan_operands(cfg)
+    plane = pl.BlockSpec((tile, G, c), lambda i: (i, 0, 0))
     out = pl.pallas_call(
-        kern,
+        functools.partial(_peel_kernel, cfg=cfg),
         grid=(padded // tile,),
-        in_specs=[
-            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
-            pl.BlockSpec((g3, 1), lambda i: (0, 0)),          # hash plan
-            pl.BlockSpec((cfg.group, 3), lambda i: (0, 0)),   # signs
-            pl.BlockSpec((tile, cfg.rows, cfg.lanes), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tile, cfg.group, cfg.lanes), lambda i: (i, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tile, cfg.group, cfg.lanes), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tile, cfg.group, cfg.lanes), lambda i: (i, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((padded, cfg.group, cfg.lanes), jnp.float32),
-            jax.ShapeDtypeStruct((padded, cfg.group, cfg.lanes), jnp.int8),
-        ],
+        in_specs=[pl.BlockSpec((tile, G, 3), lambda i: (i, 0, 0)),
+                  *plan_specs,
+                  pl.BlockSpec((tile, R, c), lambda i: (i, 0, 0)),
+                  plane],
+        out_specs=[plane, plane],
+        out_shape=[jax.ShapeDtypeStruct((padded, G, c), jnp.float32),
+                   jax.ShapeDtypeStruct((padded, G, c), jnp.int8)],
+        scratch_shapes=peel_scratch(cfg),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(ids2d, rows_tbl, signs, sketch, bits.astype(jnp.int8))
+    )(rot, *plan, sketch, bits)
     if padded != nb:
         out = [o[:nb] for o in out]
     return tuple(out)

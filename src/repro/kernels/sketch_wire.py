@@ -11,29 +11,31 @@ about, and the one THC's low-overhead codec discipline targets).
 This module fuses each trio into ONE `pallas_call` grid pass:
 
 - **producer** (`encode_pack_quantize_pallas`): gradient blocks HBM→VMEM
-  once; each grid cell runs the shared :func:`encode_tile` contraction,
-  packs the tile's non-zero bitmap into uint32 words *in VMEM*, reduces
-  the per-block max magnitude (the fxp32 exponent ingredient — a free
-  byproduct of the tile already being resident), and optionally applies
-  the shared-exponent int32 quantization before the sketch ever reaches
+  once; each block runs the shared :func:`encode_block`, packs its
+  non-zero bitmap into 32-bit words *in VMEM*, reduces the per-block max
+  magnitude (the fxp32 exponent ingredient — a free byproduct of the
+  block already being resident), and optionally applies the
+  shared-exponent int32 quantization before the sketch ever reaches
   HBM. Wire payload out, gradients in, one pass.
 - **consumer** (`dequant_peel_unpack_pallas`): wire payload HBM→VMEM
-  once; each cell unpacks its bitmap words, optionally dequantizes the
+  once; each block unpacks its bitmap words, optionally dequantizes the
   int32 sketch by exponent-field bitcast (:func:`repro.net.fixedpoint.pow2`
   — exact powers of two, never `exp2`), and runs the shared
-  :func:`peel_tile` loop to recovered values + int8 residual.
+  :func:`peel_block` loop to recovered values + int8 residual.
 
-Both kernels *reuse the exact tile cores* of the unfused kernels
-(`encode_tile` / `peel_tile`) and the exact word ordering of
+Both kernels *reuse the exact block cores* of the unfused kernels
+(`encode_block` / `peel_block`) and the exact word ordering of
 `core/index.pack_bits`, so bit-for-bit parity with the composed path is
 structural: there is one implementation of the math, fused and unfused
 paths differ only in how many times the stream crosses HBM.
 
-Packing constraint: the bitmap is packed per block, so the pack-word
-boundary must align with the block boundary — `block_elems % 32 == 0`
-(`repro.kernels.ops.fused_wire_supported`). `bucket_quantum =
-lcm(block_elems, 32)` makes default geometries satisfy this; the ops
-layer falls back to the composed reference otherwise.
+Packing constraint: the bitmap is packed per batch row, so a pack word
+must not straddle two rows — ``lanes % 32 == 0``
+(`repro.kernels.ops.fused_wire_supported`); the ops layer falls back to
+the composed reference otherwise. In the kernel a row's words are a
+(G, c/32) int32 plane, reshaped and bitcast to the flat uint32 wire
+words outside. Per-block fxp32 exponents ride in SMEM, one scalar per
+block.
 
 The fxp32 quantize leg takes *precomputed* exponents: deriving shared
 exponents needs a cross-worker `pmax`, a collective that cannot live
@@ -44,106 +46,134 @@ stream* is still read exactly once. The quantized producer leg exists
 for known-exponent callers and parity tests; the dequant consumer leg is
 always fused (exponents ride the wire).
 
-VMEM adds over the unfused kernels are small: the packed words tile is
-`B * block_elems/32 * 4` bytes (1/32 of the x tile) and maxabs is
-`B * 4` bytes; budgets stay as documented in `sketch_encode.py` /
-`sketch_peel.py`.
+VMEM adds over the unfused kernels are small: the words tile is
+`B * G * c/32 * 4` bytes (1/32 of the x tile, lane-padded in VMEM) and
+the maxabs tile `B` padded (1, 1) cells; budgets stay as documented in
+`sketch_encode.py` / `sketch_peel.py`.
 """
 
 from __future__ import annotations
 
 import functools
 
-import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro import compat
 from repro.core.config import CompressionConfig
-from repro.core import hashing
 from repro.net.fixedpoint import pow2
-from .sketch_encode import encode_tile, _plan_matrix
-from .sketch_peel import peel_tile
+from .sketch_encode import (block_rotations, encode_block, pad_blocks,
+                            tile_geometry)
+from .sketch_peel import peel_block, peel_scratch, plan_operands
 
 
-def _pack_tile_bits(x, cfg: CompressionConfig):
-    """(B, G, c) values -> (B, wpb) uint32 packed non-zero bitmap.
+def _lane(cfg: CompressionConfig):
+    return jax.lax.broadcasted_iota(jnp.int32, (cfg.group, cfg.lanes), 1)
+
+
+def _pack_block_bits(x, w_ref, b, cfg: CompressionConfig):
+    """(G, c) values -> ``w_ref[b]`` (G, c/32) int32 packed non-zero
+    bitmap.
 
     Bit order matches :func:`repro.core.index.pack_bits` on the
-    flattened block exactly: word w, bit k covers flat element
-    ``w * 32 + k`` of the block — so per-block words, flattened across
-    blocks, are bit-identical to the global pack (requires
-    ``block_elems % 32 == 0``).
-    """
-    B = x.shape[0]
-    wpb = cfg.block_elems // 32
-    bits = (x != 0).reshape(B, wpb, 32).astype(jnp.uint32)
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    return jnp.sum(bits << shifts[None, None, :], axis=-1)
+    flattened block exactly: word ``(i, q)`` bit ``k`` covers element
+    ``(i, 32q + k)``, i.e. flat element ``w * 32 + k`` of word ``w = i *
+    c/32 + q`` (requires ``c % 32 == 0``). Each word is the lane sum of
+    its 32 distinct bit values — an exact OR in int32."""
+    lane = _lane(cfg)
+    v = jnp.where(x != 0, jnp.left_shift(1, lane % 32), 0).astype(jnp.int32)
+    seg = lane // 32
+    for q in range(cfg.lanes // 32):
+        w_ref[b, :, pl.ds(q, 1)] = jnp.sum(jnp.where(seg == q, v, 0),
+                                           axis=1, keepdims=True)
 
 
-def _unpack_tile_bits(words, cfg: CompressionConfig):
-    """(B, wpb) uint32 -> (B, G, c) bool — inverse of `_pack_tile_bits`."""
-    B = words.shape[0]
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    bits = (words[:, :, None] >> shifts[None, None, :]) & jnp.uint32(1)
-    return bits.reshape(B, cfg.group, cfg.lanes) != 0
+def _unpack_block_bits(w_ref, b, cfg: CompressionConfig):
+    """``w_ref[b]`` (G, c/32) int32 words -> (G, c) int32 0/1 — inverse
+    of :func:`_pack_block_bits`."""
+    lane = _lane(cfg)
+    seg = lane // 32
+    shape = (cfg.group, cfg.lanes)
+    rep = jnp.broadcast_to(w_ref[b, :, pl.ds(0, 1)], shape)
+    for q in range(1, cfg.lanes // 32):
+        rep = jnp.where(seg == q,
+                        jnp.broadcast_to(w_ref[b, :, pl.ds(q, 1)], shape), rep)
+    return jax.lax.shift_right_logical(rep, lane % 32) & 1
 
 
-def _wire_encode_kernel(ids_ref, plan_ref, x_ref, sk_ref, w_ref, mx_ref, *,
-                        cfg: CompressionConfig):
-    ids = ids_ref[...][:, 0]                                          # (B,)
-    x = x_ref[...]
-    sk_ref[...] = encode_tile(ids, plan_ref[...], x, cfg)
-    w_ref[...] = _pack_tile_bits(x, cfg)
-    mx_ref[...] = jnp.max(jnp.abs(sk_ref[...]), axis=(1, 2))[:, None]
+def _lane_pow2(k, cfg: CompressionConfig):
+    """Scalar int32 ``k`` -> (1, c) f32 ``2**k`` (the exponent-field
+    bitcast runs on the vector unit)."""
+    return pow2(jnp.full((1, cfg.lanes), k, jnp.int32))
 
 
-def _wire_encode_q_kernel(ids_ref, plan_ref, x_ref, exp_ref,
-                          sk_ref, w_ref, mx_ref, *,
-                          cfg: CompressionConfig, mantissa_bits: int):
-    ids = ids_ref[...][:, 0]
-    x = x_ref[...]
-    acc = encode_tile(ids, plan_ref[...], x, cfg)                     # f32
-    w_ref[...] = _pack_tile_bits(x, cfg)
-    mx_ref[...] = jnp.max(jnp.abs(acc), axis=(1, 2))[:, None]
-    scale = pow2(mantissa_bits - exp_ref[...][:, 0])                  # (B,)
-    sk_ref[...] = jnp.rint(acc * scale[:, None, None]).astype(jnp.int32)
+def _block_maxabs(sk):
+    """(rows, c) -> (1, 1) max magnitude."""
+    return jnp.max(jnp.max(jnp.abs(sk), axis=1, keepdims=True), axis=0,
+                   keepdims=True)
 
 
-def _wire_peel_kernel(ids_ref, rows_ref, signs_ref, y_ref, w_ref,
-                      xo_ref, ro_ref, *, cfg: CompressionConfig):
-    ids = ids_ref[...][:, 0]
-    b = _unpack_tile_bits(w_ref[...], cfg)
-    values, residual = peel_tile(ids, rows_ref[:, 0], signs_ref[...],
-                                 y_ref[...], b, cfg)
-    xo_ref[...] = values
-    ro_ref[...] = residual.astype(jnp.int8)
+def _wire_encode_kernel(rot_ref, x_ref, *refs, cfg: CompressionConfig,
+                        mantissa_bits):
+    if mantissa_bits is None:
+        sk_ref, w_ref, mx_ref, plane_ref, acc_ref = refs
+        exp_ref = None
+    else:
+        exp_ref, sk_ref, w_ref, mx_ref, plane_ref, acc_ref = refs
+
+    def body(b, carry):
+        x = x_ref[b].astype(jnp.float32)
+
+        def out_row(r, row):
+            acc_ref[pl.ds(r, 1), :] = row
+        encode_block(x, rot_ref[b], cfg, plane_ref, out_row)
+        acc = acc_ref[...]
+        _pack_block_bits(x, w_ref, b, cfg)
+        mx_ref[b] = _block_maxabs(acc)
+        if exp_ref is None:
+            sk_ref[b] = acc
+        else:
+            scale = _lane_pow2(mantissa_bits - exp_ref[0, 0, b], cfg)
+            sk_ref[b] = jnp.rint(acc * scale).astype(jnp.int32)
+        return carry
+
+    jax.lax.fori_loop(0, x_ref.shape[0], body, 0)
 
 
-def _wire_peel_dq_kernel(ids_ref, rows_ref, signs_ref, y_ref, w_ref, exp_ref,
-                         xo_ref, ro_ref, *,
-                         cfg: CompressionConfig, mantissa_bits: int):
-    ids = ids_ref[...][:, 0]
-    b = _unpack_tile_bits(w_ref[...], cfg)
-    scale = pow2(exp_ref[...][:, 0] - mantissa_bits)                  # (B,)
-    y = y_ref[...].astype(jnp.float32) * scale[:, None, None]
-    values, residual = peel_tile(ids, rows_ref[:, 0], signs_ref[...],
-                                 y, b, cfg)
-    xo_ref[...] = values
-    ro_ref[...] = residual.astype(jnp.int8)
+def _wire_peel_kernel(rot_ref, tbl_ref, sgn_ref, y_ref, w_ref, *refs,
+                      cfg: CompressionConfig, mantissa_bits):
+    if mantissa_bits is None:
+        xo_ref, ro_ref, *scratch = refs
+        exp_ref = None
+    else:
+        exp_ref, xo_ref, ro_ref, *scratch = refs
+
+    def body(b, carry):
+        bits = _unpack_block_bits(w_ref, b, cfg)
+        y = y_ref[b].astype(jnp.float32)
+        if exp_ref is not None:
+            y = y * _lane_pow2(exp_ref[0, 0, b] - mantissa_bits, cfg)
+        values, residual = peel_block(y, bits, rot_ref[b], tbl_ref, sgn_ref,
+                                      cfg, scratch)
+        xo_ref[b] = values
+        ro_ref[b] = residual.astype(jnp.int8)
+        return carry
+
+    jax.lax.fori_loop(0, y_ref.shape[0], body, 0)
 
 
-def _pad_blocks(arrays, pads1d, nb, padded):
-    """Zero-pad leading (block) dim from nb to padded."""
-    if padded == nb:
-        return list(arrays) + list(pads1d)
-    out = [jnp.pad(a, ((0, padded - nb),) + ((0, 0),) * (a.ndim - 1))
-           for a in arrays]
-    out += [jnp.pad(p, (0, padded - nb)) for p in pads1d]
-    return out
+def _exponent_operand(exponents, padded: int, tile: int):
+    """(nb,) per-block int32 exponents -> (operand, SMEM block spec):
+    one row of ``tile`` scalars per grid cell, read on the scalar unit."""
+    spec = pl.BlockSpec((1, 1, tile), lambda i: (i, 0, 0),
+                        memory_space=pltpu.SMEM)
+    op = pad_blocks(jnp.asarray(exponents, jnp.int32), padded)
+    return op.reshape(padded // tile, 1, tile), spec
 
 
+@compat.per_device
 def encode_pack_quantize_pallas(xb: jnp.ndarray, block_ids: jnp.ndarray,
                                 cfg: CompressionConfig,
                                 exponents: jnp.ndarray | None = None,
@@ -159,52 +189,45 @@ def encode_pack_quantize_pallas(xb: jnp.ndarray, block_ids: jnp.ndarray,
     """
     nb = xb.shape[0]
     quantize = exponents is not None
-    wpb = cfg.block_elems // 32
-    tile = max(1, min(cfg.encode_block_tile, nb))
-    padded = -(-nb // tile) * tile
+    G, c, R = cfg.group, cfg.lanes, cfg.rows
+    wpr = c // 32
+    tile, padded = tile_geometry(nb, cfg.encode_block_tile)
+    cell = lambda i: (i, 0, 0)
+    in_specs = [pl.BlockSpec((tile, G, 3), cell),
+                pl.BlockSpec((tile, G, c), cell)]
+    operands = [pad_blocks(block_rotations(block_ids, cfg), padded),
+                pad_blocks(xb, padded)]
     if quantize:
         # Padding exponent 0 only scales padded all-zero blocks: harmless.
-        xb, block_ids, exponents = _pad_blocks(
-            [xb], [block_ids, jnp.asarray(exponents, jnp.int32)], nb, padded)
-    else:
-        xb, block_ids = _pad_blocks([xb], [block_ids], nb, padded)
-    plan = jnp.asarray(_plan_matrix(cfg))
-    ids2d = block_ids.reshape(padded, 1).astype(jnp.int32)
-    in_specs = [
-        pl.BlockSpec((tile, 1), lambda i: (i, 0)),
-        pl.BlockSpec((cfg.rows, cfg.group * 3), lambda i: (0, 0)),
-        pl.BlockSpec((tile, cfg.group, cfg.lanes), lambda i: (i, 0, 0)),
-    ]
-    operands = [ids2d, plan, xb]
-    if quantize:
-        kern = functools.partial(_wire_encode_q_kernel, cfg=cfg,
-                                 mantissa_bits=int(mantissa_bits))
-        in_specs.append(pl.BlockSpec((tile, 1), lambda i: (i, 0)))
-        operands.append(exponents.reshape(padded, 1).astype(jnp.int32))
-        sk_dtype = jnp.int32
-    else:
-        kern = functools.partial(_wire_encode_kernel, cfg=cfg)
-        sk_dtype = jnp.float32
-    out = pl.pallas_call(
-        kern,
+        op, spec = _exponent_operand(exponents, padded, tile)
+        in_specs.append(spec)
+        operands.append(op)
+    sk, words, mx = pl.pallas_call(
+        functools.partial(_wire_encode_kernel, cfg=cfg,
+                          mantissa_bits=(int(mantissa_bits) if quantize
+                                         else None)),
         grid=(padded // tile,),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((tile, cfg.rows, cfg.lanes), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tile, wpb), lambda i: (i, 0)),
-            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
-        ],
+        out_specs=[pl.BlockSpec((tile, R, c), cell),
+                   pl.BlockSpec((tile, G, wpr), cell),
+                   pl.BlockSpec((tile, 1, 1), cell)],
         out_shape=[
-            jax.ShapeDtypeStruct((padded, cfg.rows, cfg.lanes), sk_dtype),
-            jax.ShapeDtypeStruct((padded, wpb), jnp.uint32),
-            jax.ShapeDtypeStruct((padded, 1), jnp.float32),
+            jax.ShapeDtypeStruct((padded, R, c),
+                                 jnp.int32 if quantize else jnp.float32),
+            jax.ShapeDtypeStruct((padded, G, wpr), jnp.int32),
+            jax.ShapeDtypeStruct((padded, 1, 1), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((G, c), jnp.float32),
+                        pltpu.VMEM((R, c), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*operands)
-    sk, words, mx = (o[:nb] for o in out) if padded != nb else out
-    return sk, words, mx[:, 0]
+    words = jax.lax.bitcast_convert_type(words, jnp.uint32)
+    return (sk[:nb], words[:nb].reshape(nb, G * wpr), mx[:nb, 0, 0])
 
 
+@compat.per_device
 def dequant_peel_unpack_pallas(sketch: jnp.ndarray, words: jnp.ndarray,
                                block_ids: jnp.ndarray,
                                cfg: CompressionConfig,
@@ -218,50 +241,34 @@ def dequant_peel_unpack_pallas(sketch: jnp.ndarray, words: jnp.ndarray,
     """
     nb = sketch.shape[0]
     dequant = exponents is not None
-    wpb = cfg.block_elems // 32
-    tile = max(1, min(cfg.peel_block_tile, nb))
-    padded = -(-nb // tile) * tile
+    G, c, R = cfg.group, cfg.lanes, cfg.rows
+    wpr = c // 32
+    tile, padded = tile_geometry(nb, cfg.peel_block_tile)
+    cell = lambda i: (i, 0, 0)
+    words = jax.lax.bitcast_convert_type(words, jnp.int32).reshape(nb, G, wpr)
+    plan, plan_specs = plan_operands(cfg)
+    in_specs = [pl.BlockSpec((tile, G, 3), cell), *plan_specs,
+                pl.BlockSpec((tile, R, c), cell),
+                pl.BlockSpec((tile, G, wpr), cell)]
+    operands = [pad_blocks(block_rotations(block_ids, cfg), padded), *plan,
+                pad_blocks(sketch, padded), pad_blocks(words, padded)]
     if dequant:
-        sketch, words, block_ids, exponents = _pad_blocks(
-            [sketch, words],
-            [block_ids, jnp.asarray(exponents, jnp.int32)], nb, padded)
-    else:
-        sketch, words, block_ids = _pad_blocks(
-            [sketch, words], [block_ids], nb, padded)
-    g3 = cfg.group * 3
-    rows_tbl = jnp.asarray(
-        hashing.batch_rows(cfg.group, cfg.rows, cfg.seed).reshape(g3, 1))
-    signs = jnp.asarray(hashing.batch_signs(cfg.group, cfg.seed))
-    ids2d = block_ids.reshape(padded, 1).astype(jnp.int32)
-    in_specs = [
-        pl.BlockSpec((tile, 1), lambda i: (i, 0)),
-        pl.BlockSpec((g3, 1), lambda i: (0, 0)),
-        pl.BlockSpec((cfg.group, 3), lambda i: (0, 0)),
-        pl.BlockSpec((tile, cfg.rows, cfg.lanes), lambda i: (i, 0, 0)),
-        pl.BlockSpec((tile, wpb), lambda i: (i, 0)),
-    ]
-    operands = [ids2d, rows_tbl, signs, sketch, words]
-    if dequant:
-        kern = functools.partial(_wire_peel_dq_kernel, cfg=cfg,
-                                 mantissa_bits=int(mantissa_bits))
-        in_specs.append(pl.BlockSpec((tile, 1), lambda i: (i, 0)))
-        operands.append(exponents.reshape(padded, 1).astype(jnp.int32))
-    else:
-        kern = functools.partial(_wire_peel_kernel, cfg=cfg)
+        op, spec = _exponent_operand(exponents, padded, tile)
+        in_specs.append(spec)
+        operands.append(op)
+    plane = pl.BlockSpec((tile, G, c), cell)
     out = pl.pallas_call(
-        kern,
+        functools.partial(_wire_peel_kernel, cfg=cfg,
+                          mantissa_bits=(int(mantissa_bits) if dequant
+                                         else None)),
         grid=(padded // tile,),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((tile, cfg.group, cfg.lanes), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tile, cfg.group, cfg.lanes), lambda i: (i, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((padded, cfg.group, cfg.lanes), jnp.float32),
-            jax.ShapeDtypeStruct((padded, cfg.group, cfg.lanes), jnp.int8),
-        ],
+        out_specs=[plane, plane],
+        out_shape=[jax.ShapeDtypeStruct((padded, G, c), jnp.float32),
+                   jax.ShapeDtypeStruct((padded, G, c), jnp.int8)],
+        scratch_shapes=peel_scratch(cfg),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*operands)
-    if padded != nb:
-        out = [o[:nb] for o in out]
-    return tuple(out)
+    return tuple(o[:nb] for o in out)

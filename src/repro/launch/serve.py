@@ -116,7 +116,9 @@ def main():
     from repro.configs import get_arch
     from repro.models import model_api
     from repro.serve import ServeEngine, ContinuousBatcher, Request
+    from repro.launch.cache import use_compile_cache
 
+    use_compile_cache()
     arch = get_arch(args.arch)
     cfg = arch.smoke if args.smoke else arch.model
     api = model_api(cfg)

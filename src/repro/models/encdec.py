@@ -15,7 +15,6 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from .config import ModelConfig
 from . import layers as L
 from repro.parallel.hints import constrain
@@ -104,15 +103,14 @@ def encdec_loss(params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
     S = x.shape[1]
 
     def body(xx, p_l):
-        # in-body iota: a hoisted positions constant becomes a scan
-        # operand whose sharding annotation breaks 0.4.x partial-auto
-        # manual regions (see repro.compat)
+        # in-body iota: no hoisted positions constant among the scan
+        # operands
         out, _, _ = _dec_block(xx, p_l, cfg, enc_out,
                                jnp.arange(S)[None, :])
         return out, None
 
     if remat in ("block", "dots"):
-        body = compat.checkpoint(body, prevent_cse=False)
+        body = jax.checkpoint(body, prevent_cse=False)
     x, _ = jax.lax.scan(body, x, params["dec_layers"])
     x = L.layernorm(x, params["dec_ln"], cfg.norm_eps)
     logits = L.mask_padded_vocab(

@@ -17,7 +17,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from .config import ModelConfig, MoEConfig
 from repro.parallel.hints import constrain
 
@@ -171,12 +170,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     vs = vp.reshape(B, nk, bk, H, hd).transpose(1, 0, 2, 3, 4)
 
     # Block indices ride in the scan *carries* and positions are built by
-    # in-body iotas: a constant (arange) among the scan xs picks up a
-    # plain replicated sharding annotation, which the 0.4.x partitioner
-    # cannot carry through a partial-auto manual region (fatal
-    # IsManualSubgroup check). Carry counters are annotation-free on
-    # every JAX and numerically identical.
-    @compat.checkpoint  # flash backward: recompute probs per q-block
+    # in-body iotas, so no constant (arange) rides among the scan xs.
+    @jax.checkpoint  # flash backward: recompute probs per q-block
     def q_step(qi, q_blk):  # instead of saving the O(Sq*Skv) attn matrix
         q_pos = q_offset + qi * bq + jnp.arange(bq)     # (bq,)
 

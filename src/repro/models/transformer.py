@@ -19,7 +19,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from .config import ModelConfig
 from . import layers as L
 from . import ssm as S
@@ -160,10 +159,8 @@ def lm_hidden(params, cfg: ModelConfig, tokens, vis_embed=None,
     x = _embed(params, cfg, tokens, vis_embed)
     Sq = x.shape[1]
 
-    # positions is built *inside* each scan body: hoisted outside, the
-    # iota becomes a scan-level constant operand whose replicated sharding
-    # annotation aborts the 0.4.x partitioner in partial-auto manual
-    # regions (see repro.compat); in-body it is a plain iota op.
+    # positions is built *inside* each scan body, so the iota is a plain
+    # op there rather than a scan-level constant operand.
     def _positions():
         return jnp.arange(Sq)[None, :]
 
@@ -197,11 +194,11 @@ def lm_hidden(params, cfg: ModelConfig, tokens, vis_embed=None,
         stacked = params["layers"]
 
     if remat == "block":
-        body = compat.checkpoint(body, prevent_cse=False)
+        body = jax.checkpoint(body, prevent_cse=False)
     elif remat == "block_nocse":
-        body = compat.checkpoint(body)
+        body = jax.checkpoint(body)
     elif remat == "dots":
-        body = compat.checkpoint(
+        body = jax.checkpoint(
             body, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
             prevent_cse=False)
 

@@ -14,10 +14,7 @@ only** and rejects float operands — the float sketch must go through the
 fixed-point wire first (:mod:`repro.net.fixedpoint`). Because integer
 adds and ORs are exactly associative/commutative, the tree result is
 bit-identical to a flat ``psum`` / OR-AllReduce of the same operands,
-which is also the fallback wire on JAX legs whose partitioner cannot run
-``ppermute`` in the calling region (same gating as the reduce-scatter
-wire — ``compat.SUPPORTS_PARTIAL_AUTO_PPERMUTE``, or a full-manual
-caller).
+which is also the wire taken with ``use_ppermute=False``.
 
 Chunk/port ordering follows :func:`repro.core.collectives.linear_rank`:
 worker *w*'s switch port is its rank-major linear index over the DP
@@ -43,7 +40,6 @@ from typing import Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core.collectives import _check_axis_indices, or_allreduce
 
 from .fixedpoint import ceil_log2
@@ -198,7 +194,7 @@ def reduce_to_root(x: jnp.ndarray, axis_name: str, combine: str,
     receives zeros, the identity of both combiners).
     """
     del idx
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     comb = _combine_fn(combine, x.dtype)
     d = 1
     while d < n:
@@ -214,7 +210,7 @@ def broadcast_from_root(x: jnp.ndarray, axis_name: str,
     in ceil(log2 n) ppermute steps. ``idx``: this shard's index on the
     axis — pass it when calling from a nested region (see
     :func:`repro.core.collectives.or_allreduce_ring`)."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     if idx is None:
@@ -230,7 +226,7 @@ def broadcast_from_root(x: jnp.ndarray, axis_name: str,
 
 def tree_all_reduce(x: jnp.ndarray, topo: Topology, combine: str,
                     axis_indices: Optional[dict] = None,
-                    use_ppermute: Optional[bool] = None,
+                    use_ppermute: bool = True,
                     window_slots: Optional[int] = None) -> jnp.ndarray:
     """Reduce-to-root + broadcast over the topology's levels.
 
@@ -241,11 +237,8 @@ def tree_all_reduce(x: jnp.ndarray, topo: Topology, combine: str,
     switch cannot sum floats; see :mod:`repro.net.fixedpoint`).
 
     Because both combiners are exact, the result is bit-identical to the
-    flat collective over the same axes — which is also the fallback when
-    ``ppermute`` is unsupported in the calling region (``use_ppermute``
-    mirrors :func:`repro.core.collectives.or_reduce_scatter`: ``None``
-    follows ``compat.SUPPORTS_PARTIAL_AUTO_PPERMUTE``; full-manual
-    callers on 0.4.x should pass True).
+    flat collective over the same axes — which is what
+    ``use_ppermute=False`` runs instead of the ppermute tree.
 
     ``window_slots`` is the windowed mode (PR 5): the leading dim of
     ``x`` is a stream of chunks (e.g. buckets) and the tree reduces at
@@ -274,8 +267,6 @@ def tree_all_reduce(x: jnp.ndarray, topo: Topology, combine: str,
                                 use_ppermute=use_ppermute)
                 for w0 in range(0, n, window_slots)]
             return jnp.concatenate(parts, axis=0)
-    if use_ppermute is None:
-        use_ppermute = compat.SUPPORTS_PARTIAL_AUTO_PPERMUTE
     if not use_ppermute:
         if combine == "add":
             return jax.lax.psum(x, tuple(topo.levels))

@@ -68,5 +68,6 @@ def constrain(x: jax.Array, logical_spec) -> jax.Array:
         from jax.sharding import NamedSharding
         return jax.lax.with_sharding_constraint(
             x, NamedSharding(mesh, P(*parts)))
-    from repro import compat
-    return compat.manual_region_constraint(x, P(*parts))
+    # inside a manual region: the bare spec resolves against the
+    # context mesh
+    return jax.lax.with_sharding_constraint(x, P(*parts))

@@ -11,6 +11,7 @@ Control flow on failure (simulated or real):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any, Callable, Dict, List, Optional
 
@@ -48,14 +49,19 @@ def run_training(api: ModelAPI, tc: TrainConfig, mesh, *,
     monitor = StragglerMonitor()
     saver = ckpt.AsyncCheckpointer()
 
-    state = init_train_state(api, tc, mesh, jax.random.PRNGKey(tc.seed))
+    # The state is created directly in its shardings: each device
+    # materialises only its own share of the parameters, optimizer state
+    # and (dp, ...) error-feedback residual.
+    init = functools.partial(init_train_state, api, tc, mesh)
+    key = jax.random.PRNGKey(tc.seed)
     make = build_train_step(api, tc, mesh)
-    step_fn, specs = make(state)
+    step_fn, specs = make(jax.eval_shape(init, key))
+    init = jax.jit(init, out_shardings=specs["named"])
+    state = init(key)
     _, bnamed = batch_specs(make_batch(0), mesh, tc)
     jitted = jax.jit(step_fn, in_shardings=(specs["named"], bnamed),
                      out_shardings=(specs["named"], None),
                      donate_argnums=(0,))
-    state = jax.device_put(state, specs["named"])
 
     # resume if a checkpoint exists
     start = 0
@@ -105,10 +111,7 @@ def run_training(api: ModelAPI, tc: TrainConfig, mesh, *,
             last = ckpt.latest_step(ckpt_dir)
             if last is None:
                 # no checkpoint yet: restart from scratch
-                state = jax.device_put(
-                    init_train_state(api, tc, mesh,
-                                     jax.random.PRNGKey(tc.seed)),
-                    specs["named"])
+                state = init(key)
                 step = 0
             else:
                 state = ckpt.restore(ckpt_dir, last, template=state,

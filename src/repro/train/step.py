@@ -18,8 +18,7 @@ The step is organised exactly like the paper's Algorithm 1 deployment:
      gather-skip path: when the stream chunk grid aligns with the
      ZeRO-1 slices, per-rank recovered chunks feed the optimizer
      shards directly and the recovered-chunk all_gather disappears
-     (``tc.rs_gather_skip``); emulated by psum + slice on 0.4.x
-     partial-auto), or
+     (``tc.rs_gather_skip``)), or
      ``"compressed_innet"`` (the emulated in-network tier of PR 4: the
      stream rides a worker->ToR->spine switch tree from ``repro.net``
      once per worker — integer-add sketch over the fixed-point wire
@@ -227,7 +226,7 @@ def build_train_step(api: ModelAPI, tc: TrainConfig, mesh, *,
         if rules_mesh is not None:
             return jax.lax.with_sharding_constraint(
                 x, NamedSharding(rules_mesh, spec))
-        return compat.manual_region_constraint(x, spec)
+        return jax.lax.with_sharding_constraint(x, spec)
 
     def local_grads(params, batch, pspecs):
         """Per-worker gradients, with optional microbatch accumulation."""
@@ -279,7 +278,7 @@ def build_train_step(api: ModelAPI, tc: TrainConfig, mesh, *,
     # region. Compression packs shard-locally even in pure-DP profiles:
     # vocab-sharded embedding grads would otherwise be all-gathered to
     # full size before encoding (16+ GiB/step on a 3B model).
-    step_manual = compat.train_step_manual_axes(mesh, dp_axes)
+    step_manual = set(dp_axes)
     aggregator = agg_lib.make_aggregator(
         tc.aggregator if dp > 1 else "dense", tc.compression, mesh,
         dp_axes=dp_axes, tp_axes=((prof.tp_axis or "model"),),
@@ -287,10 +286,10 @@ def build_train_step(api: ModelAPI, tc: TrainConfig, mesh, *,
     if wire_plan is not None and not isinstance(aggregator,
                                                 agg_lib.DenseAggregator):
         aggregator = dataclasses.replace(aggregator, wire_plan=wire_plan)
-    # Full-manual step regions (0.4.x always; new JAX when the mesh has
-    # only DP axes) can gather ZeRO-1 slices with a manual-axis
-    # all_gather — no auto axes left for Shardy to un-shard, and half
-    # the wire of the zero-pad + psum trick kept for partial-auto.
+    # Full-manual step regions (the mesh has only DP axes) can gather
+    # ZeRO-1 slices with a manual-axis all_gather — no auto axes left
+    # for Shardy to un-shard, and half the wire of the zero-pad + psum
+    # trick kept for partial-auto.
     manual_all_gather = bool(dp_axes) and \
         compat.full_manual_region(step_manual, mesh)
 
@@ -311,8 +310,7 @@ def build_train_step(api: ModelAPI, tc: TrainConfig, mesh, *,
         ex_cfg = dataclasses.replace(tc.compression, ratio=2.5,
                                      topk_ratio=None, error_feedback=False)
         ep_exchange = agg_lib.make_exchange(
-            tc.ep_exchange, ex_cfg, mesh, ep_axes_eff,
-            outer_manual=step_manual)
+            tc.ep_exchange, ex_cfg, mesh, ep_axes_eff)
 
     def make_aggregate(agg):
         def aggregate(grads, residual, pspecs):
@@ -442,7 +440,7 @@ def build_train_step(api: ModelAPI, tc: TrainConfig, mesh, *,
                     inner, mesh=mesh,
                     in_specs=(sm.params, sm.opt, sm.residual, P(), bm),
                     out_specs=(sm.params, sm.opt, sm.residual, P()),
-                    axis_names=compat.train_step_manual_axes(mesh, dp_axes),
+                    axis_names=step_manual,
                     check_vma=False)
             else:
                 fn = inner          # no DP axes: pure auto-sharded step

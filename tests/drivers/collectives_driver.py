@@ -21,7 +21,7 @@ Validates, on a (2, 2, 2) pod/data/model mesh:
      reduce (power-of-2 and non-power-of-2 axes, single and multi axis,
      rank-major chunk order pinned against psum_scatter's).
   7. the native reduce-scatter wire (psum_scatter sketch + OR-RS bitmap,
-     full-manual region so it runs on BOTH JAX legs) is bit-identical to
+     full-manual region) is bit-identical to
      the emulated psum+slice wire and to CompressedAggregator over 3
      error-feedback steps.
   8. the in-network tier (PR 4): tree_all_reduce (ppermute reduce-to-root
@@ -96,10 +96,8 @@ got = jax.jit(shard_map(
 assert np.array_equal(np.asarray(got), expect), "OR-allreduce mismatch"
 print("OK or_allreduce hierarchical")
 
-# ring + doubling individually over one axis. Full-manual region: on
-# 0.4.x the partitioner cannot run ppermute while other axes stay auto
-# (see repro.compat.SUPPORTS_PARTIAL_AUTO_PPERMUTE), and taking every
-# axis manual tests the collective itself on every JAX.
+# ring + doubling individually over one axis, in a full-manual region
+# so the collective itself is what is tested.
 words2 = rng.integers(0, 2**32, size=(2, 100_000), dtype=np.uint32)
 from repro.core.collectives import or_allreduce_ring, or_allreduce_doubling
 for name, fn in [("ring", or_allreduce_ring), ("doubling", or_allreduce_doubling)]:
@@ -216,7 +214,7 @@ def run_ef(overlap, name="compressed", rs_wire="auto", wire_plan=None,
     cfg = dataclasses.replace(cfg_ef, overlap=overlap, rs_wire=rs_wire,
                               **overrides)
     # The region below takes every mesh axis manual, so declare it:
-    # full-manual callers unlock the native RS wire on every JAX leg.
+    # full-manual callers reassemble with a manual-axis all_gather.
     agg = make_aggregator(name, cfg, mesh, ("pod", "data"), (),
                           outer_manual=("pod", "data", "model"),
                           wire_plan=wire_plan)
@@ -332,8 +330,7 @@ putRS = jax.device_put(jnp.asarray(wordsRS.reshape(2, 2, -1)),
 gotRS = np.asarray(jax.jit(shard_map(
     lambda a: or_reduce_scatter(
         a[0, 0], ("pod", "data"),
-        axis_indices={ax: jax.lax.axis_index(ax) for ax in ("pod", "data")},
-        use_ppermute=True),
+        axis_indices={ax: jax.lax.axis_index(ax) for ax in ("pod", "data")}),
     mesh=mesh, in_specs=P("pod", "data", None),
     out_specs=P(("pod", "data")), axis_names={"pod", "data", "model"},
     check_vma=False))(putRS))
@@ -731,7 +728,7 @@ for label, wp in mixed_plans:
 # bitmap OR in flight, ratio 2.5 = always-exact peel) must equal the
 # dense wire AND the numpy reference bit-for-bit, over 3 steps of
 # evolving payloads, on the native single-axis ppermute leg (W=2 over
-# "data"; the region is full-manual so it runs on both JAX legs), the
+# "data"; the region is full-manual), the
 # psum-emulated multi-axis leg (W=4 over pod x data), and a chunked
 # (stream_chunks=2) lane grid.
 from repro.core.aggregators import make_exchange
@@ -761,8 +758,7 @@ for label, ep_axes, w_ep, in_spec, out_spec in (
         outs = {}
         for wire in ("dense", "compressed"):
             cfg_w = dataclasses.replace(cfg_a2a, stream_chunks=chunks)
-            ex = make_exchange(wire, cfg_w, mesh, ep_axes,
-                               outer_manual=("pod", "data", "model"))
+            ex = make_exchange(wire, cfg_w, mesh, ep_axes)
 
             def body(stack, ex=ex, n_lead=len(ep_axes)):
                 local = stack

@@ -176,7 +176,11 @@ assert all(abs(a - b) < 1e-4 for a, b in zip(l_rs, l_rs_ov)), \
 # 4-bucket leaves align with the ZeRO-1 slices on a 2-chunk grid. With
 # tc.rs_gather_skip the step must drop the recovered-chunk all_gather
 # (fewer all_gather eqns in the jaxpr) and train identically (the only
-# off-shard consumer, the grad-norm, is psum-reduced on that path).
+# off-shard consumer, the grad-norm, is psum-reduced on that path). The
+# stub runs on a DP-only mesh, whose step region is full-manual, so the
+# recovered-chunk gather is a manual-axis all_gather (a partial-auto
+# region gathers with zero-pad + psum instead).
+stub_mesh = make_mesh((2, 2), ("pod", "data"), devices=jax.devices()[:4])
 from repro.models.registry import ModelAPI
 
 E_skip = 1536  # bucket_elems of the skip compression config below
@@ -213,9 +217,9 @@ def run_stub(rs_gather_skip):
     tc = TrainConfig(aggregator="compressed_rs", optimizer=opt,
                      compression=skip_comp, sharding=stub_prof,
                      remat="none", rs_gather_skip=rs_gather_skip)
-    state = init_train_state(stub_api, tc, mesh, jax.random.PRNGKey(0))
-    step_fn, specs = build_train_step(stub_api, tc, mesh)(state)
-    _, bnamed = batch_specs(stub_batch, mesh, tc)
+    state = init_train_state(stub_api, tc, stub_mesh, jax.random.PRNGKey(0))
+    step_fn, specs = build_train_step(stub_api, tc, stub_mesh)(state)
+    _, bnamed = batch_specs(stub_batch, stub_mesh, tc)
     n_ag = str(jax.make_jaxpr(step_fn)(state, stub_batch)).count("all_gather")
     jitted = jax.jit(step_fn, in_shardings=(specs["named"], bnamed),
                      out_shardings=(specs["named"], None))

@@ -9,14 +9,11 @@ Per strategy:
 - ``dense``                 — per-link bytes of the leaf psums must equal
   ``link_bytes`` exactly (ring AllReduce, ``2(W-1)/W x`` payload).
 - ``compressed``            — sketch psum at the ring factor plus the
-  bitmap OR. On a leg with partial-auto ppermute the bitmap rides the
-  OR-ring at the same factor and the total equals ``link_bytes``
-  exactly; on the pinned 0.4.x leg ``or_allreduce`` is psum-emulated at
-  the documented ``or_emulated_factor`` (32x) — after dividing that
-  factor back out of the index traffic, the totals must still agree.
+  bitmap OR-ring at the same factor; the total equals ``link_bytes``
+  exactly.
 - ``compressed_rs`` native  — psum_scatter sketch + OR-Reduce-Scatter
-  bitmap + recovered-chunk all_gather; ppermute-based and full-manual,
-  so it must equal ``link_bytes`` (gather included) exactly on BOTH legs.
+  bitmap + recovered-chunk all_gather; it must equal ``link_bytes``
+  (gather included) exactly.
 - ``compressed_rs`` emulate — AllReduce wire (psum + local slice): same
   expected bytes as ``compressed`` — plus the recovered-chunk all_gather
   the implementation launches to reassemble the per-rank peeled chunks.
@@ -36,7 +33,7 @@ Per strategy:
 The stream is sized so the packed bitmap is >= 64 KiB: above
 ``or_allreduce``'s ring threshold, so the ppermute leg takes the
 bandwidth-optimal ring at W=4 (recursive doubling would cost
-``log2(W) x`` instead and the cross-check would be leg-dependent).
+``log2(W) x`` instead).
 """
 import os
 os.environ.setdefault(
@@ -68,8 +65,6 @@ cfg = CompressionConfig(ratio=0.3, lanes=128, rows=6, rounds=10,
                         chunk_blocks=64, use_pallas="never",
                         bucket_bytes=2560 * 16 * 4)
 
-EMULATED_OR = not compat.SUPPORTS_PARTIAL_AUTO_PPERMUTE
-print(f"leg: or_allreduce {'psum-emulated (0.4.x)' if EMULATED_OR else 'ppermute ring'}")
 
 
 def dyadic(n, seed, frac=0.03):
@@ -125,7 +120,6 @@ for W in (2, 4):
     print(f"OK W={W} dense: measured {round(got)} == analytic {want}")
 
     # ---- compressed + emulated RS: AllReduce wire --------------------
-    or_factor = acc["or_emulated_factor"] if EMULATED_OR else 1
     emu_gather = acc["compressed_rs_native"]["rs_gather_link_bytes"]
     for name, rs_wire, key, extra in (
             ("compressed", "auto", "compressed", 0),
@@ -133,20 +127,18 @@ for W in (2, 4):
              emu_gather)):
         _, jx = jaxpr_of(name, rs_wire=rs_wire)
         got = _count_link_bytes(jx, W)
-        want = ring * (sketch_full + or_factor * idx_full) + extra
+        want = ring * (sketch_full + idx_full) + extra
         assert round(got) == round(want), (W, key, got, want)
-        # dividing the documented emulation factor (and the emulated
-        # arm's recovered-chunk gather) back out of the traffic must
-        # recover the analytic link accounting
-        normalized = got - ring * (or_factor - 1) * idx_full - extra
-        assert abs(normalized - acc[key]["link_bytes"]) <= 1, \
-            (W, key, normalized, acc[key]["link_bytes"])
+        # taking the emulated arm's recovered-chunk gather back out of
+        # the traffic must recover the analytic link accounting
+        assert abs(got - extra - acc[key]["link_bytes"]) <= 1, \
+            (W, key, got - extra, acc[key]["link_bytes"])
         print(f"OK W={W} {key}: measured {round(got)} == "
-              f"sketch*ring + {or_factor}x index*ring"
+              f"sketch*ring + index*ring"
               + (f" + gather {extra}" if extra else "")
               + f" (analytic {acc[key]['link_bytes']})")
 
-    # ---- native RS: ppermute wire, exact on both legs ----------------
+    # ---- native RS: ppermute wire, exact ------------------------------
     _, jx = jaxpr_of("compressed_rs", rs_wire="native")
     got = _count_link_bytes(jx, W)
     want = acc["compressed_rs_native"]["link_bytes"]
@@ -192,8 +184,7 @@ for W in (2, 4):
     a2a_payload = {"g": jnp.asarray(np.stack(
         [dyadic(n_d, seed=100 + w) for w in range(W)]))}
     for wire in ("dense_alltoall", "compressed_alltoall"):
-        ex = make_exchange(wire.split("_")[0], cfg, mesh, ("data",),
-                           outer_manual=("data",))
+        ex = make_exchange(wire.split("_")[0], cfg, mesh, ("data",))
         fn = jax.jit(compat.shard_map(
             lambda p, ex=ex: jax.tree.map(lambda l: l[None], ex(p)),
             mesh=mesh, in_specs=({"g": P()},),

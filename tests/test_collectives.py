@@ -50,7 +50,7 @@ def test_non_power_of_two_axes_take_ring(n, ring):
 
 def test_or_allreduce_single_shard_identity():
     # axis size 1 on a trivial mesh context: both branches short-circuit.
-    # (No shard_map needed: compat.axis_size is only consulted per axis,
+    # (No shard_map needed: jax.lax.axis_size is only consulted per axis,
     # and an empty axis list never consults it.)
     x = jnp.asarray(np.arange(8, dtype=np.uint32))
     out = or_allreduce(x, ())
@@ -115,8 +115,7 @@ def test_psum_or_emulation_chunk_invariant():
 
 # ----------------------------------------------------------------------
 # compressed_all_reduce must forward outer_manual (regression: the
-# wrapper used to drop it, so fully-manual callers silently degraded to
-# all-ranks peeling over the emulated wire on 0.4.x)
+# wrapper used to drop it)
 # ----------------------------------------------------------------------
 
 def test_compressed_all_reduce_forwards_outer_manual(monkeypatch):
@@ -141,8 +140,7 @@ def test_compressed_all_reduce_forwards_outer_manual(monkeypatch):
 
 def test_compressed_all_reduce_native_rs_through_wrapper():
     """End-to-end: rs_wire='native' must work through the wrapper when
-    the caller declares a full-manual region — on 0.4.x this is exactly
-    the configuration the dropped ``outer_manual`` used to break."""
+    the caller declares a full-manual region."""
     cfg = CompressionConfig(ratio=1.0, lanes=128, rows=6, rounds=10,
                             chunk_blocks=8, rs_wire="native",
                             bucket_bytes=768 * 4)

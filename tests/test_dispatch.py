@@ -222,9 +222,8 @@ def test_innet_wires_match_plain_bitwise(wire_dtype, backend):
 
 
 # The harness mesh has only the (manual) "data" axis, so the region is
-# full-manual and the native psum_scatter + OR-Reduce-Scatter wire runs
-# on BOTH JAX legs — including pinned 0.4.x — not just where
-# compat.SUPPORTS_PSUM_SCATTER is set.
+# full-manual and the native wire reassembles with a manual-axis
+# all_gather.
 @pytest.mark.parametrize("wire", ["native", "emulate"])
 @pytest.mark.parametrize("backend", ["never", "always"])
 def test_rs_wire_paths_match_plain_bitwise(wire, backend):
@@ -383,8 +382,7 @@ def _a2a_payload(seed):
 
 def _run_exchange(cfg, name, steps=3):
     mesh = make_mesh((1,), ("data",))
-    exchange = make_exchange(name, cfg, mesh, ("data",),
-                             outer_manual=("data",))
+    exchange = make_exchange(name, cfg, mesh, ("data",))
 
     def fn(payload):
         return exchange(payload)
@@ -433,7 +431,6 @@ def test_exchange_backend_parity_bitwise():
 def test_exchange_rejects_bloom_index():
     cfg = dataclasses.replace(A2A_BASE, index="bloom")
     mesh = make_mesh((1,), ("data",))
-    exchange = make_exchange("compressed", cfg, mesh, ("data",),
-                             outer_manual=("data",))
+    exchange = make_exchange("compressed", cfg, mesh, ("data",))
     with pytest.raises(ValueError, match="bitmap"):
         exchange(jax.tree.map(jnp.asarray, _a2a_payload(0)))

@@ -223,12 +223,11 @@ def test_gather_skip_rejects_misaligned_grids_and_leaves():
     assert not zero1_gather_skip(splan, plan, None)
 
 
-def test_gather_skip_guard_keys_off_actual_leaf_sharding(monkeypatch):
+def test_gather_skip_guard_keys_off_actual_leaf_sharding():
     """The nested-packing guard must look at whether any leaf is really
     sharded on a non-DP axis — NOT at which axes the mesh merely has:
     a pure-DP profile on a mesh that also carries a (unused) model axis
-    must still get the skip on every JAX generation."""
-    from repro import compat
+    must still get the skip."""
     from repro.core.aggregators import make_aggregator
 
     class FakeMesh:  # shape/axis_names are all the aggregator reads
@@ -243,15 +242,10 @@ def test_gather_skip_guard_keys_off_actual_leaf_sharding(monkeypatch):
             "b": np.zeros(4 * 768, np.float32)}
     repl = {"a": P(), "b": P()}
     tp = {"a": P("model"), "b": P()}
-    for nested in (False, True):
-        monkeypatch.setattr(compat, "SUPPORTS_NESTED_SHARD_MAP", nested)
-        # replicated leaves: the stream is the global view either way
-        assert agg.gather_skip_active(tree, repl), nested
-    # a genuinely TP-sharded leaf: 0.4.x still packs the global view,
-    # nested JAX packs a TP-local stream -> alignment math invalid
-    monkeypatch.setattr(compat, "SUPPORTS_NESTED_SHARD_MAP", False)
-    assert agg.gather_skip_active(tree, tp)
-    monkeypatch.setattr(compat, "SUPPORTS_NESTED_SHARD_MAP", True)
+    # replicated leaves: the packed stream is the global view
+    assert agg.gather_skip_active(tree, repl)
+    # a genuinely TP-sharded leaf packs a TP-local stream -> the
+    # alignment math is invalid, keep the gather
     assert not agg.gather_skip_active(tree, tp)
 
 
