@@ -292,6 +292,7 @@ class CompressedAggregator:
 
     def _reduce_allreduce(self, dp_idx):
         """The AllReduce wire for one (sketch, words) payload chunk."""
+        @jax.named_scope("reduce")
         def red(payload):
             sk, words = payload
             return (jax.lax.psum(sk, tuple(self.dp_axes)),
@@ -317,6 +318,7 @@ class CompressedAggregator:
         per-chunk payload (the fxp32 wire's exponent ingredient — free
         on the fused path, where the producer kernel emits it anyway).
         """
+        @jax.named_scope("encode")
         def enc(i, chunk):
             leaf, mx = comp.compress_wire(
                 chunk.reshape(-1),
@@ -350,12 +352,10 @@ class CompressedAggregator:
         (the fxp32 tree adds the shared exponents)."""
         splan = self._stream_plan(plan)
         if not splan.streamed:
-            c = comp.compress(buckets.reshape(-1),
-                              block_offset=self.base_block)
-            sk = jax.lax.psum(c.sketch, tuple(self.dp_axes))
-            words = or_allreduce(c.index_words, self.dp_axes,
-                                 axis_indices=dp_idx)
-            return sk, words
+            with jax.named_scope("encode"):
+                c = comp.compress(buckets.reshape(-1),
+                                  block_offset=self.base_block)
+            return self._reduce_allreduce(dp_idx)((c.sketch, c.index_words))
         sks, ws = self._encode_streamed(buckets, splan, comp,
                                         self._reduce_allreduce(dp_idx))
         return self._trim_fused(sks, ws, plan, splan)
@@ -370,8 +370,9 @@ class CompressedAggregator:
         the reduce-scatter subclass consults them (the gather-skip path
         must know whether the packed stream is a TP-local view)."""
         sk, words = payload
-        rec = comp.recover(CompressedLeaf(sketch=sk, index_words=words),
-                           plan.padded, block_offset=self.base_block)
+        with jax.named_scope("peel"):
+            rec = comp.recover(CompressedLeaf(sketch=sk, index_words=words),
+                               plan.padded, block_offset=self.base_block)
         return rec.reshape(plan.n_buckets, plan.bucket_elems)
 
     # -- plan / execute (PR 6) -----------------------------------------
@@ -435,7 +436,8 @@ class CompressedAggregator:
         for g in wplan.groups:
             bgroup = buckets[g.start:g.stop]
             if g.wire == "dense":
-                parts.append(jax.lax.psum(bgroup, tuple(self.dp_axes)))
+                with jax.named_scope("reduce"):
+                    parts.append(jax.lax.psum(bgroup, tuple(self.dp_axes)))
                 continue
             gview = plan.group_view(g.start, g.n_buckets)
             delegate = self._group_delegate(g, base_block=g.start * nbpb)
@@ -480,10 +482,12 @@ class CompressedAggregator:
         plan = make_bucket_plan(
             grads, cfg, shapes=jax.tree.unflatten(treedef, local_shapes))
 
+        @jax.named_scope("pack")
         def pack_stage(g_tree, r_tree):
             """Shard-local: per-leaf sparsify/EF, then bucket-pack."""
             return pack_stream(plan, g_tree, r_tree, cfg)
 
+        @jax.named_scope("unpack")
         def unpack_stage(buckets):
             """Shard-local: bucket stream -> leaf pytree (mean)."""
             return plan.unpack(buckets / n_workers)
@@ -647,8 +651,9 @@ class CompressedReduceScatterAggregator(CompressedAggregator):
         if not self._native_wire() or self._dp_world() == 1:
             if self._native_wire() and not self._stream_plan(plan).streamed:
                 # 1-rank native wire: nothing to scatter or reduce.
-                c = comp.compress(buckets.reshape(-1),
-                                  block_offset=self.base_block)
+                with jax.named_scope("encode"):
+                    c = comp.compress(buckets.reshape(-1),
+                                      block_offset=self.base_block)
                 return c.sketch, c.index_words
             return super()._encode(buckets, plan, comp, dp_idx)
         splan = self._stream_plan(plan)
@@ -657,19 +662,22 @@ class CompressedReduceScatterAggregator(CompressedAggregator):
                                          self._reduce_scatter(dp_idx))
         # One-shot native wire: a single psum_scatter + OR-RS over the
         # whole stream, padded to whole per-rank chunks.
-        c = comp.compress(buckets.reshape(-1), block_offset=self.base_block)
         W, nbpb, wpb, nb_p = self._rs_geometry(plan)
-        sk, words = c.sketch, c.index_words
-        pad_b = nb_p - plan.n_buckets
-        if pad_b:
-            # zero sketch blocks / zero index words peel to exact zeros
-            sk = jnp.pad(sk, ((0, pad_b * nbpb), (0, 0), (0, 0)))
-            words = jnp.pad(words, (0, pad_b * wpb))
+        with jax.named_scope("encode"):
+            c = comp.compress(buckets.reshape(-1),
+                              block_offset=self.base_block)
+            sk, words = c.sketch, c.index_words
+            pad_b = nb_p - plan.n_buckets
+            if pad_b:
+                # zero sketch blocks / zero index words peel to exact zeros
+                sk = jnp.pad(sk, ((0, pad_b * nbpb), (0, 0), (0, 0)))
+                words = jnp.pad(words, (0, pad_b * wpb))
         return self._reduce_scatter(dp_idx)((sk, words))
 
     def _reduce_scatter(self, dp_idx):
         """The native wire for one (sketch, words) payload chunk: each
         rank receives its own fully-reduced whole-bucket slice."""
+        @jax.named_scope("reduce")
         def red(payload):
             sk, words = payload
             sk_loc = jax.lax.psum_scatter(
@@ -695,22 +703,24 @@ class CompressedReduceScatterAggregator(CompressedAggregator):
                                               dp_idx, dp_rank, spec_leaves)
             # (sk, words) are already this rank's reduced 1/W slice (the
             # whole stream at W == 1).
-            rec_loc = comp.recover(
-                CompressedLeaf(sketch=sk, index_words=words), chunk_elems,
-                block_offset=self.base_block + dp_rank * chunk_b * nbpb)
+            with jax.named_scope("peel"):
+                rec_loc = comp.recover(
+                    CompressedLeaf(sketch=sk, index_words=words), chunk_elems,
+                    block_offset=self.base_block + dp_rank * chunk_b * nbpb)
             return self._gather_chunks(rec_loc, plan, nb_p, chunk_elems,
                                        dp_rank)
-        pad_b = nb_p - plan.n_buckets
-        if pad_b:
-            sk = jnp.pad(sk, ((0, pad_b * nbpb), (0, 0), (0, 0)))
-            words = jnp.pad(words, (0, pad_b * wpb))
-        sk_loc = jax.lax.dynamic_slice_in_dim(
-            sk, dp_rank * chunk_b * nbpb, chunk_b * nbpb, axis=0)
-        w_loc = jax.lax.dynamic_slice_in_dim(
-            words, dp_rank * chunk_b * wpb, chunk_b * wpb, axis=0)
-        rec_loc = comp.recover(
-            CompressedLeaf(sketch=sk_loc, index_words=w_loc), chunk_elems,
-            block_offset=self.base_block + dp_rank * chunk_b * nbpb)
+        with jax.named_scope("peel"):
+            pad_b = nb_p - plan.n_buckets
+            if pad_b:
+                sk = jnp.pad(sk, ((0, pad_b * nbpb), (0, 0), (0, 0)))
+                words = jnp.pad(words, (0, pad_b * wpb))
+            sk_loc = jax.lax.dynamic_slice_in_dim(
+                sk, dp_rank * chunk_b * nbpb, chunk_b * nbpb, axis=0)
+            w_loc = jax.lax.dynamic_slice_in_dim(
+                words, dp_rank * chunk_b * wpb, chunk_b * wpb, axis=0)
+            rec_loc = comp.recover(
+                CompressedLeaf(sketch=sk_loc, index_words=w_loc), chunk_elems,
+                block_offset=self.base_block + dp_rank * chunk_b * nbpb)
         return self._gather_chunks(rec_loc, plan, nb_p, chunk_elems, dp_rank)
 
     def _recover_streamed(self, sk, words, plan: BucketPlan,
@@ -731,22 +741,26 @@ class CompressedReduceScatterAggregator(CompressedAggregator):
                 block_offset=splan.rank_slice_start_block(j, dp_rank))
 
         idx = jnp.arange(splan.n_chunks, dtype=jnp.int32)
-        rec = jax.lax.map(peel, (idx, sk, words))  # (n_chunks, slice_elems)
-        if self._gather_skip(plan, splan, spec_leaves):
-            full = jnp.zeros((splan.n_chunks, splan.chunk_elems), rec.dtype)
-            full = jax.lax.dynamic_update_slice(
-                full, rec, (jnp.int32(0), dp_rank * slice_elems))
-        else:
-            # Same gate as _gather_chunks: the manual-axis all_gather
-            # only in full-manual regions — partial-auto keeps the
-            # zero-pad + psum trick so Shardy does not un-shard the
-            # auto TP axes around the gather.
-            full = gather_chunk_slices(
-                rec, tuple(self.dp_axes), axis_indices=dp_idx,
-                use_all_gather=self._full_manual())
-        stream = full.reshape(-1)[:plan.padded]
-        return stream.reshape(plan.n_buckets, plan.bucket_elems)
+        with jax.named_scope("peel"):
+            rec = jax.lax.map(peel, (idx, sk, words))  # (n_chunks, slice_elems)
+        with jax.named_scope("unpack"):
+            if self._gather_skip(plan, splan, spec_leaves):
+                full = jnp.zeros((splan.n_chunks, splan.chunk_elems),
+                                 rec.dtype)
+                full = jax.lax.dynamic_update_slice(
+                    full, rec, (jnp.int32(0), dp_rank * slice_elems))
+            else:
+                # Same gate as _gather_chunks: the manual-axis all_gather
+                # only in full-manual regions — partial-auto keeps the
+                # zero-pad + psum trick so Shardy does not un-shard the
+                # auto TP axes around the gather.
+                full = gather_chunk_slices(
+                    rec, tuple(self.dp_axes), axis_indices=dp_idx,
+                    use_all_gather=self._full_manual())
+            stream = full.reshape(-1)[:plan.padded]
+            return stream.reshape(plan.n_buckets, plan.bucket_elems)
 
+    @jax.named_scope("unpack")
     def _gather_chunks(self, rec_loc, plan: BucketPlan, nb_p: int,
                        chunk_elems: int, dp_rank):
         """Reassemble the per-rank recovered chunks into the full stream.
@@ -837,6 +851,7 @@ class CompressedInNetworkAggregator(CompressedAggregator):
         splan = self._stream_plan(plan)
         nbpb = splan.blocks_per_bucket
 
+        @jax.named_scope("reduce")
         def tree_window(sk_buckets, maxabs_blocks, words_buckets):
             """One chunk (whole buckets) over the fxp32 tree, window by
             window: pmax-agree exponents from the producer's per-block
@@ -858,8 +873,9 @@ class CompressedInNetworkAggregator(CompressedAggregator):
             return q, w, exp
 
         if not splan.streamed:
-            c, mx = comp.compress_wire(buckets.reshape(-1),
-                                       block_offset=self.base_block)
+            with jax.named_scope("encode"):
+                c, mx = comp.compress_wire(buckets.reshape(-1),
+                                           block_offset=self.base_block)
             sk, words = c.sketch, c.index_words
             q_b, w_b, exp = tree_window(
                 sk.reshape(plan.n_buckets, -1), mx,
@@ -892,10 +908,11 @@ class CompressedInNetworkAggregator(CompressedAggregator):
         q, words, exp = payload
         wire = FixedPointWire(workers=self._dp_world())
         nbpb = plan.blocks_per_bucket(self.cfg)
-        rec = comp.recover(
-            CompressedLeaf(sketch=q, index_words=words), plan.padded,
-            block_offset=self.base_block,
-            dequant=(jnp.repeat(exp, nbpb), wire.mantissa_bits))
+        with jax.named_scope("peel"):
+            rec = comp.recover(
+                CompressedLeaf(sketch=q, index_words=words), plan.padded,
+                block_offset=self.base_block,
+                dequant=(jnp.repeat(exp, nbpb), wire.mantissa_bits))
         return rec.reshape(plan.n_buckets, plan.bucket_elems)
 
 

@@ -65,6 +65,7 @@ def _causal_conv(u: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
     return jax.nn.silu(y).astype(u.dtype), new_state
 
 
+@jax.named_scope("ssd_scan")
 def _ssd_chunk_scan(xdt, dA, Bm, Cm, chunk: int):
     """Chunked SSD. xdt: (B,S,H,P) = x*dt;  dA: (B,S,H) = dt*A (negative);
     Bm, Cm: (B,S,N). Returns y (B,S,H,P)."""

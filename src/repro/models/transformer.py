@@ -113,12 +113,14 @@ def _apply_ffn(x, p, cfg: ModelConfig, decode: bool = False,
                              capacity_factor=cf,
                              ep_exchange=None if decode else ep_exchange)
         return out.reshape(B, Sq, D), aux
-    return L.mlp(x, p["ffn"]), jnp.float32(0.0)
+    with jax.named_scope("mlp"):
+        return L.mlp(x, p["ffn"]), jnp.float32(0.0)
 
 
 def _attn_block(x, p, cfg: ModelConfig, positions, ep_exchange=None):
     h = L.rmsnorm(x, p["ln1"], cfg.norm_eps)
-    o, kv = L.attention_train(h, p["attn"], cfg, positions=positions)
+    with jax.named_scope("attention"):
+        o, kv = L.attention_train(h, p["attn"], cfg, positions=positions)
     x = x + o
     h = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
     ff, aux = _apply_ffn(h, p, cfg, ep_exchange=ep_exchange)
@@ -127,7 +129,8 @@ def _attn_block(x, p, cfg: ModelConfig, positions, ep_exchange=None):
 
 def _ssm_block(x, p, cfg: ModelConfig, ep_exchange=None):
     h = L.rmsnorm(x, p["ln1"], cfg.norm_eps)
-    x = x + S.mamba_forward(h, p["mamba"], cfg)
+    with jax.named_scope("mamba"):
+        x = x + S.mamba_forward(h, p["mamba"], cfg)
     if "ln2" in p:
         h = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
         ff, aux = _apply_ffn(h, p, cfg, ep_exchange=ep_exchange)
@@ -140,10 +143,11 @@ def _ssm_block(x, p, cfg: ModelConfig, ep_exchange=None):
 # ----------------------------------------------------------------------
 
 def _embed(params, cfg: ModelConfig, tokens, vis_embed=None):
-    x = jnp.take(params["embed"], tokens, axis=0)
-    if vis_embed is not None:
-        x = jnp.concatenate([vis_embed.astype(x.dtype), x], axis=1)
-    return constrain(x, ("dp", None, None))
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
+        if vis_embed is not None:
+            x = jnp.concatenate([vis_embed.astype(x.dtype), x], axis=1)
+        return constrain(x, ("dp", None, None))
 
 
 def _unembed(params, cfg: ModelConfig, x):
@@ -219,12 +223,13 @@ def lm_loss(params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
                        ep_exchange=ep_exchange)
     if vis is not None:
         x = x[:, vis.shape[1]:]                     # text positions only
-    logits = _unembed(params, cfg, x)
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    ll = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    nll = jnp.mean(lse - ll)
-    zloss = 1e-4 * jnp.mean(jnp.square(lse))
-    loss = nll + zloss + 0.01 * aux
+    with jax.named_scope("head_loss"):
+        logits = _unembed(params, cfg, x)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        ll = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+        nll = jnp.mean(lse - ll)
+        zloss = 1e-4 * jnp.mean(jnp.square(lse))
+        loss = nll + zloss + 0.01 * aux
     return loss, {"nll": nll, "aux": aux, "zloss": zloss}
 
 

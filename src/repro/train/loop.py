@@ -1,6 +1,11 @@
 """Training loop: step dispatch + checkpointing + failure recovery +
 straggler accounting. This is the piece a cluster job actually runs.
 
+Each step runs inside ``jax.profiler.StepTraceAnnotation("train")``
+with host spans ``train.batch``, ``train.dispatch``, ``train.readback``
+(the ``float(loss)`` that waits for the step) and ``train.checkpoint``,
+so a ``jax.profiler`` trace of a job puts the host beside the device.
+
 Control flow on failure (simulated or real):
   detect -> (optionally shrink world / rebuild mesh) -> restore last
   checkpoint with resharding -> replay the deterministic data stream from
@@ -17,6 +22,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.ckpt import checkpoint as ckpt
 from repro.data.pipeline import batch_fn
@@ -77,29 +83,34 @@ def run_training(api: ModelAPI, tc: TrainConfig, mesh, *,
     step = start
     while step < steps:
         try:
-            t0 = time.perf_counter()
-            if failure_sim is not None:
-                failure_sim.check(step)
-            batch = jax.tree.map(lambda a, s: jax.device_put(a, s),
-                                 make_batch(step), bnamed)
-            state, metrics = jitted(state, batch)
-            loss = float(metrics["loss"])
-            dt = time.perf_counter() - t0
-            monitor.observe(step, dt)
-            losses.append(loss)
-            # vector metrics (e.g. the `auto` strategy's per-bucket
-            # occupancy telemetry) are kept as lists, scalars as floats
-            all_metrics.append({
-                k: float(v) if np.ndim(v) == 0
-                else np.asarray(v).tolist()
-                for k, v in metrics.items()})
-            if log_every and step % log_every == 0:
-                log_fn(f"[loop] step {step} loss {loss:.4f} "
-                       f"({dt*1e3:.0f} ms)")
-            step += 1
-            if ckpt_dir and step % ckpt_every == 0:
-                saver.save(ckpt_dir, step, state,
-                           metadata={"loss": loss})
+            with StepTraceAnnotation("train", step_num=step):
+                t0 = time.perf_counter()
+                if failure_sim is not None:
+                    failure_sim.check(step)
+                with TraceAnnotation("train.batch"):
+                    batch = jax.tree.map(lambda a, s: jax.device_put(a, s),
+                                         make_batch(step), bnamed)
+                with TraceAnnotation("train.dispatch"):
+                    state, metrics = jitted(state, batch)
+                with TraceAnnotation("train.readback"):
+                    loss = float(metrics["loss"])
+                dt = time.perf_counter() - t0
+                monitor.observe(step, dt)
+                losses.append(loss)
+                # vector metrics (e.g. the `auto` strategy's per-bucket
+                # occupancy telemetry) are kept as lists, scalars as floats
+                all_metrics.append({
+                    k: float(v) if np.ndim(v) == 0
+                    else np.asarray(v).tolist()
+                    for k, v in metrics.items()})
+                if log_every and step % log_every == 0:
+                    log_fn(f"[loop] step {step} loss {loss:.4f} "
+                           f"({dt*1e3:.0f} ms)")
+                step += 1
+                if ckpt_dir and step % ckpt_every == 0:
+                    with TraceAnnotation("train.checkpoint"):
+                        saver.save(ckpt_dir, step, state,
+                                   metadata={"loss": loss})
         except InjectedFailure as e:
             restarts += 1
             log_fn(f"[loop] FAILURE detected: {e}; restart {restarts}")

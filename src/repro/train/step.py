@@ -313,6 +313,7 @@ def build_train_step(api: ModelAPI, tc: TrainConfig, mesh, *,
             tc.ep_exchange, ex_cfg, mesh, ep_axes_eff)
 
     def make_aggregate(agg):
+        @jax.named_scope("aggregate")
         def aggregate(grads, residual, pspecs):
             if isinstance(agg, agg_lib.DenseAggregator):
                 return coll.dense_all_reduce(grads, dp_axes), residual, None
@@ -331,6 +332,7 @@ def build_train_step(api: ModelAPI, tc: TrainConfig, mesh, *,
         # ZeRO-1 slice placement matches psum_scatter/all_gather tiling.
         return coll.linear_rank(dp_axes)
 
+    @jax.named_scope("optimizer")
     def apply_updates(params, opt, grads, step, pspecs, norm_psum=False):
         lr = opt_lib.lr_schedule(step, ocfg)
         gnorm = opt_lib.global_grad_norm(grads)
