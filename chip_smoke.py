@@ -1,11 +1,16 @@
 #!/usr/bin/env python3
 """Chip smoke test: drive the system's main path once on a TPU and check it.
 
-    python chip_smoke.py                # one chip: train + codec phases
+    python chip_smoke.py                # one chip: attention, train, codec
     python chip_smoke.py --four-chips   # four chips: the data-parallel wires
 
 One chip (the default):
 
+- **attention** — granite's training attention at its published widths
+  (B 1, S 4096, 32 heads over 8 KV heads, hd 64, bfloat16): the
+  dispatching ``flash_attention`` must lower to the fused TPU kernel and
+  agree with the blockwise path, forward output and q, k, v gradients,
+  within ``ATTENTION_RTOL`` of the largest value.
 - **train** — granite-3-2b (``configs/granite_3_2b.py``) at its published
   widths (d_model 2048, 32 heads, GQA kv 8, d_ff 8192, vocab 49155) cut
   to ``--n-layers`` (4) layers, seq_len 4096, global batch 8 with the
@@ -424,6 +429,55 @@ def phase_codec(args, trained):
     check(unpeeled == 0, f"{label}: {unpeeled} non-zeros left unpeeled")
 
 
+# The attention check: largest |kernel - blockwise| over the largest
+# |blockwise|, for the output and each gradient. Both compute scores in
+# float32 from bfloat16 q and k and round the output to bfloat16, so they
+# differ by a few bfloat16 ulps of the largest value (each reads 3e-3 to
+# 8e-3 against a float32 reference on a TPU v5e); a wrong mask, scale or
+# head order reads of order 1.
+ATTENTION_RTOL = 3e-2
+
+
+def phase_attention(args):
+    """Granite's training attention at its published widths (B 1, S 4096,
+    32 heads over 8 KV heads, hd 64, bfloat16): the dispatching
+    ``flash_attention`` must lower to the fused kernel on the chip and
+    agree with the blockwise path, forward output and q, k, v gradients."""
+    import jax
+    import jax.numpy as jnp
+    from repro.models import layers as L
+
+    _, cfg = granite(args.n_layers)
+    h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    ks = jax.random.split(jax.random.PRNGKey(args.seed), 4)
+    q, w = (jax.random.normal(k, (1, SEQ_LEN, h, hd), jnp.bfloat16)
+            for k in ks[:2])
+    k, v = (jax.random.normal(k, (1, SEQ_LEN, kv, hd), jnp.bfloat16)
+            for k in ks[2:])
+
+    def value_and_grads(attn):
+        def run(q, k, v, w):
+            out, vjp = jax.vjp(attn, q, k, v)
+            return (out,) + vjp(w)
+        return jax.jit(run).lower(q, k, v, w).compile()
+
+    kernel = value_and_grads(
+        lambda q, k, v: L.flash_attention(q, k, v, True, cfg.q_block))
+    check("splash_mha" in kernel.as_text(),
+          "attention: flash_attention did not lower to the fused kernel")
+    blockwise = value_and_grads(
+        lambda q, k, v: L.blockwise_attention(q, k, v, True, cfg.q_block))
+    got, want = kernel(q, k, v, w), blockwise(q, k, v, w)
+    worst = 0.0
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        gap = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+        log(f"attention: {name} relative gap kernel vs blockwise {gap:.3e}")
+        worst = max(worst, gap)
+    check(worst <= ATTENTION_RTOL,
+          f"attention: kernel vs blockwise gap {worst:.3e} > {ATTENTION_RTOL}")
+
+
 def phase_four_chips(args, mesh):
     from repro.models import model_api
     from repro.parallel.sharding import ShardingProfile
@@ -484,6 +538,7 @@ def main(argv=None) -> int:
             phase_four_chips(args, mesh)
         else:
             mesh = make_host_mesh()
+            phase_attention(args)
             phase_codec(args, phase_train(args, mesh))
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr, flush=True)

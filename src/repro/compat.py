@@ -13,16 +13,20 @@ the repo's conventions on top of those APIs in one place:
   — JAX rejects the concrete, all-``Auto`` mesh there
   (:func:`shard_map`);
 - a Pallas kernel called where mesh axes are still auto runs per device
-  (:func:`per_device`).
+  (:func:`per_device`), and a caller that may choose a kernel asks how
+  many devices those axes span (:func:`auto_devices`).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Iterable, Optional, Sequence
 
 import jax
 from jax.sharding import PartitionSpec as P
+
+from repro.parallel import hints
 
 
 def full_manual_region(manual_axes, mesh) -> bool:
@@ -71,6 +75,25 @@ def shard_map(f, *, mesh, in_specs, out_specs,
                          check_vma=check_vma)
 
 
+def _auto_axes(ctx) -> set:
+    """The axes of the context mesh ``ctx`` not taken manual."""
+    return set() if ctx.empty else set(ctx.axis_names) - set(ctx.manual_axes)
+
+
+def auto_devices() -> int:
+    """Devices the compiler would partition a call over at this point of
+    the trace: those of the context mesh's axes not taken manual (which
+    :func:`per_device` takes manual around a kernel), or, under plain
+    jit, of the mesh the sharding hints carry
+    (:func:`repro.parallel.hints.active_mesh`). A kernel can be called
+    only where this is 1."""
+    ctx = jax.sharding.get_abstract_mesh()
+    if not ctx.empty:
+        return math.prod(ctx.shape[a] for a in _auto_axes(ctx))
+    mesh = hints.active_mesh()
+    return 1 if mesh is None else mesh.size
+
+
 def per_device(kernel):
     """Decorator: run ``kernel`` on each device's own copy of its arrays.
 
@@ -86,8 +109,7 @@ def per_device(kernel):
     @functools.wraps(kernel)
     def run(*args, **kwargs):
         ctx = jax.sharding.get_abstract_mesh()
-        auto = set() if ctx.empty else set(ctx.axis_names) - set(
-            ctx.manual_axes)
+        auto = _auto_axes(ctx)
         if not ctx.manual_axes or not auto:
             return kernel(*args, **kwargs)
         leaves, tree = jax.tree.flatten((args, kwargs))

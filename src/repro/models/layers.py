@@ -16,8 +16,10 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
 from .config import ModelConfig, MoEConfig
+from repro import compat
 from repro.parallel.hints import constrain
 
 
@@ -128,12 +130,101 @@ def _project_qkv(x, p, cfg: ModelConfig, kv_input=None):
     return q, k, v
 
 
+# The fused TPU kernel: JAX's splash attention, multi-head, with its fused
+# backward. It takes no score scale, so q is scaled before the call, which
+# is exact only where 1/sqrt(hd) is a power of two: of the registry's head
+# sizes, 64 (hd 128 would round q twice). Its tiles are the largest power
+# of two up to the cap that divides the length, at least the kernel's
+# 128-lane minimum; the caps were chosen on a TPU v5e at S 4096, hd 64
+# (``benchmarks/attention_sweep.py``).
+_KERNEL_HEAD_DIM = 64
+_KERNEL_MIN_BLOCK = 128
+_KERNEL_BLOCK = 1024
+_KERNEL_KV_COMPUTE = 512
+
+
+def attention_kernel_blocks(sq: int, skv: int, hd: int, q_offset: int,
+                            backend: str, auto_devices: int
+                            ) -> Optional[splash.BlockSizes]:
+    """The fused TPU kernel's block sizes where it computes this call,
+    else None (the call takes :func:`blockwise_attention`).
+
+    The kernel engages only where it computes what the blockwise path
+    computes and can be compiled: on a TPU; self-attention from position
+    0 (``q_offset == 0``, ``sq == skv``), so its causal mask ``col <= row``
+    is the program's; a length that divides by its blocks; the head size
+    whose scale is exact (``_KERNEL_HEAD_DIM``); and a call the compiler
+    need not partition (``auto_devices`` 1), since Mosaic kernels cannot
+    be partitioned: heads sharded over a ``tp`` axis of more than one
+    device keep the blockwise path.
+    """
+    if (backend != "tpu" or q_offset != 0 or sq != skv or auto_devices != 1
+            or hd != _KERNEL_HEAD_DIM):
+        return None
+    b = _KERNEL_BLOCK
+    while sq % b:
+        b //= 2
+    if b < _KERNEL_MIN_BLOCK:
+        return None
+    compute = min(b, _KERNEL_KV_COMPUTE)
+    return splash.BlockSizes(
+        block_q=b, block_kv=b, block_kv_compute=compute,
+        block_q_dkv=b, block_kv_dkv=b, block_kv_dkv_compute=compute,
+        use_fused_bwd_kernel=True)
+
+
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     causal: bool, q_block: int, kv_block: int = 1024,
                     q_offset: int = 0) -> jnp.ndarray:
+    """Attention for training and prefill: the fused TPU kernel
+    (:func:`kernel_attention`) where :func:`attention_kernel_blocks` admits
+    the call, else :func:`blockwise_attention`. Arguments as the
+    latter's."""
+    blocks = attention_kernel_blocks(q.shape[1], k.shape[1], q.shape[-1],
+                                     q_offset, jax.default_backend(),
+                                     compat.auto_devices())
+    if blocks is None:
+        return blockwise_attention(q, k, v, causal, q_block, kv_block,
+                                   q_offset)
+    return kernel_attention(q, k, v, causal, blocks)
+
+
+def kernel_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                     causal: bool, blocks) -> jnp.ndarray:
+    """The TPU Pallas splash attention kernel that ships with JAX
+    (``jax.experimental.pallas.ops.tpu.splash_attention``): one fused
+    forward and one fused backward, skipping the blocks the causal mask
+    hides. Scores in float32 from q and k, as in the blockwise path; the
+    PV products run in float32.
+
+    q: (B, Sq, H, hd); k, v: (B, Skv, KV, hd). KV heads are expanded to the
+    full head count, as the blockwise path does, and the heads moved
+    before the sequence around the call. q enters scaled by 1/sqrt(hd),
+    exact for the head sizes :func:`attention_kernel_blocks` admits. Where
+    mesh axes are still auto (all of one device) the call runs per device
+    (:func:`repro.compat.per_device`).
+    """
+    sq, h, hd = q.shape[1:]
+    rep = h // k.shape[2]
+    mask = (splash.CausalMask if causal else splash.FullMask)((sq, k.shape[1]))
+    kernel = splash.make_splash_mha(
+        splash.MultiHeadMask([mask] * h), block_sizes=blocks,
+        head_shards=1, q_seq_shards=1)
+    heads_first = lambda x: x.transpose(0, 2, 1, 3)
+    o = compat.per_device(jax.vmap(kernel))(
+        heads_first(q * (1.0 / math.sqrt(hd))),
+        heads_first(jnp.repeat(k, rep, axis=2)),
+        heads_first(jnp.repeat(v, rep, axis=2)))
+    return heads_first(o)
+
+
+@jax.named_scope("blockwise_attention")
+def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                        causal: bool, q_block: int, kv_block: int = 1024,
+                        q_offset: int = 0) -> jnp.ndarray:
     """Blockwise online-softmax attention (the lax analogue of flash).
 
-    q: (B, Sq, KV, rep, hd);  k, v: (B, Skv, KV, hd).
+    q: (B, Sq, H, hd);  k, v: (B, Skv, KV, hd).
     Memory peak is O(bq * bk) per (batch, head) rather than O(Sq * Skv).
     ``q_offset`` positions q tokens at ``q_offset + i`` for causal masking
     (used by decode/prefill-with-cache paths).

@@ -44,6 +44,12 @@ def logical_axis_rules(rules: Optional[dict], mesh=None):
         _state.rules, _state.mesh = prev
 
 
+def active_mesh():
+    """The mesh given to the active :func:`logical_axis_rules` (plain jit),
+    or None."""
+    return getattr(_state, "mesh", None)
+
+
 def constrain(x: jax.Array, logical_spec) -> jax.Array:
     """Apply with_sharding_constraint if a rules mapping is active."""
     rules = _rules()
@@ -63,7 +69,7 @@ def constrain(x: jax.Array, logical_spec) -> jax.Array:
         parts = [None] * (x.ndim - len(parts)) + parts
     if all(p is None for p in parts):
         return x
-    mesh = getattr(_state, "mesh", None)
+    mesh = active_mesh()
     if mesh is not None:
         from jax.sharding import NamedSharding
         return jax.lax.with_sharding_constraint(

@@ -91,3 +91,27 @@ def test_kernel_compiles_for_v5e(one_chip, name, wire):
     # the kernel's operands and results fit the chip's 16 GiB with room
     assert mem.argument_size_in_bytes + mem.output_size_in_bytes \
         + mem.temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_attention_kernel_compiles_for_v5e(one_chip, causal):
+    """Granite's training attention (B 1, S 4096, 32 heads over 8 KV
+    heads, hd 64, bf16) through the fused kernel, forward and backward:
+    one forward kernel and one fused backward kernel, no blockwise loop."""
+    from benchmarks import attention_kernels
+    from repro.models import layers as L
+    blocks = L.attention_kernel_blocks(4096, 4096, 64, 0, "tpu", 1)
+    q = _arg((1, 4096, 32, 64), jnp.bfloat16, one_chip)
+    kv = _arg((1, 4096, 8, 64), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        o = L.kernel_attention(q, k, v, causal, blocks)
+        return jnp.sum(o.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile()
+    assert attention_kernels.count(compiled.as_text()) == {
+        "kernel_calls": 2, "blockwise_calls": 0,
+        "kernel_by_name": {"splash_mha_fwd_residuals": 1,
+                           "splash_mha_dkv_no_residuals": 1}}
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 30
