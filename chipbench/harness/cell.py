@@ -245,8 +245,11 @@ def run(name, seed, seconds, trace, t_start, require_tpu=True, tamper=None,
     first = readings["first"]
     readings = with_change(readings)
     t_ref = time.perf_counter()
-    ref = reference_readings(reference(cell, devices), mix, first)
+    trainer = reference(cell, devices)
+    ref = reference_readings(trainer, mix, first)
     log(f"reference {time.perf_counter() - t_ref:.3f} s, losses {ref['loss']}")
+    log("reference by phase: " + ", ".join(f"{k} {v:.3f} s" for k, v in trainer.seconds.items()))
+    memory(devices, "after the reference")
     numbers = compare(readings, ref)
     for k, v in numbers.items():
         if k not in cell["limits"]:

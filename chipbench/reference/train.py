@@ -14,11 +14,24 @@ step that ends the warm-up, as the benchmark's program state does.
 Ranks run side by side, one per device, under ``shard_map``. The codec
 splits its blocks across the same devices. Nothing here imports the
 program.
+
+Memory: the parameters and both moments live on the host between
+steps. The gradient pass takes only the parameters' float32 copy, made
+on the devices, and returns the microbatch sums (the scan's accumulator
+is the output buffer); the jitted call that consumes them divides by the
+microbatch count. The update is jitted with the parameters, the moments
+and the gradient donated, so it writes in place. A device then holds at
+most the float32 parameters, the accumulator, one microbatch's gradient
+and its activations during the gradient pass (12 bytes a parameter and
+the activations), and the parameters, moments and gradient during the
+update (14 bytes a parameter for bfloat16 parameters).
 """
 
 from __future__ import annotations
 
 import math
+import time
+from collections import defaultdict
 
 import jax
 import jax.numpy as jnp
@@ -69,11 +82,28 @@ def _adamw(params, m, v, g, lr, t, o, m_store=1.0):
     return jax.tree.map(upd, params, m, v), jax.tree.map(lambda a: a * m_store, m), v, g
 
 
+class _Clock:
+    """Seconds by phase: each call waits for ``out`` and books the
+    time since the previous call to ``phase``."""
+
+    def __init__(self, seconds):
+        self.seconds, self.t = seconds, time.perf_counter()
+
+    def __call__(self, phase, out):
+        jax.block_until_ready(out)
+        now = time.perf_counter()
+        self.seconds[phase] += now - self.t
+        self.t = now
+        return out
+
+
 class ReferenceTrainer:
     """Steps the reference. ``mode``: ``"f32"``, or ``"fp8"`` for the
-    control."""
+    control. ``seconds`` sums the wall time of each phase of the steps
+    taken (host transfers included, first calls compile)."""
 
     def __init__(self, cfg, traffic, family, devices, mode="f32"):
+        self.seconds = defaultdict(float)
         self.m, self.t = cfg["model"], cfg["train"]
         self.ranks = traffic["ranks"]
         self.accum = self.t["accum_steps"]
@@ -89,8 +119,7 @@ class ReferenceTrainer:
 
         n_micro = self.accum
 
-        def rank_grads(params, toks, labs):
-            p32 = jax.tree.map(lambda a: a.astype(jnp.float32), params)
+        def rank_grads(p32, toks, labs):
             mb = lambda x: x.reshape((self.accum, -1) + x.shape[1:])[:n_micro]
 
             def body(acc, xs):
@@ -99,19 +128,19 @@ class ReferenceTrainer:
 
             zero = jax.tree.map(jnp.zeros_like, p32)
             (loss, g), _ = jax.lax.scan(body, (jnp.float32(0), zero), (mb(toks), mb(labs)))
-            return loss / n_micro, jax.tree.map(lambda a: a / n_micro, g)
+            return loss, g      # sums: the consumer divides by n_micro
 
         ratio = self.t["compression"]["topk_ratio"]
         ef = self.t["compression"]["error_feedback"]
 
-        def grads(params, toks, labs):
-            loss, g = rank_grads(params, toks, labs)
+        def grads(p32, toks, labs):
+            loss, g = rank_grads(p32, toks, labs)
             return loss[None], jax.tree.map(lambda a: a[None], g)
 
         def pack(g, res):
             leaves, tdef = jax.tree.flatten(g)
             rl = tdef.flatten_up_to(res)
-            out = [_topk_ef(a[0].reshape(-1), r[0].reshape(-1) if ef else 0.0, ratio)
+            out = [_topk_ef((a[0] / n_micro).reshape(-1), r[0].reshape(-1) if ef else 0.0, ratio)
                    for a, r in zip(leaves, rl)]
             stream = jnp.concatenate([s for s, _ in out])
             new_res = [(nr if ef else jnp.zeros_like(nr)).reshape(r.shape)
@@ -125,7 +154,9 @@ class ReferenceTrainer:
         self._pack = jax.jit(jax.shard_map(
             pack, mesh=self.mesh, in_specs=(rk, rk), out_specs=(rk, rk),
             check_vma=False), donate_argnums=(0, 1))
-        self._adamw = jax.jit(_adamw, static_argnums=(6,))
+        self._to_f32 = jax.jit(lambda p: jax.tree.map(lambda a: a.astype(jnp.float32), p))
+        self._dense_mean = jax.jit(lambda g: jax.tree.map(lambda a: (a / n_micro).mean(0), g))
+        self._adamw = jax.jit(_adamw, static_argnums=(6,), donate_argnums=(0, 1, 2, 3))
         self._decode = jax.jit(self._aggregate_stream)
 
     def _aggregate_stream(self, streams, keep):
@@ -159,18 +190,24 @@ class ReferenceTrainer:
         return vals.reshape(-1)[:n] / R
 
     def init(self, params):
-        params = jax.device_put(params, NamedSharding(self.mesh, P()))
+        """The state from ``params``: parameters and zero moments on the
+        host, error-feedback residuals on the devices."""
+        params = jax.device_get(params)
         R = self.ranks
-
-        def zeros32(p):     # moments split across the devices where they divide
-            spec = P("r") if p.ndim and p.shape[0] % R == 0 else P()
-            return jax.device_put(jnp.zeros(p.shape, jnp.float32), NamedSharding(self.mesh, spec))
-
-        res = (jax.tree.map(lambda p: jnp.zeros((R,) + p.shape, jnp.float32), params)
-               if self.compressed else None)
-        return {"params": params, "m": jax.tree.map(zeros32, params),
-                "v": jax.tree.map(zeros32, params), "res": res,
+        res = None
+        if self.compressed:     # each rank's residuals on its own device
+            res = jax.jit(lambda: jax.tree.map(lambda p: jnp.zeros((R,) + p.shape, jnp.float32),
+                                               params),
+                          out_shardings=NamedSharding(self.mesh, P("r")))()
+        zeros = lambda p: np.zeros(p.shape, np.float32)
+        return {"params": params, "m": jax.tree.map(zeros, params),
+                "v": jax.tree.map(zeros, params), "res": res,
                 "step": self.t["optimizer"]["warmup_steps"]}
+
+    def _moment_sharding(self, p):
+        """Moments split across the devices where they divide."""
+        split = p.ndim and p.shape[0] % self.ranks == 0
+        return NamedSharding(self.mesh, P("r") if split else P())
 
     def step(self, st, batch, fault=None):
         """One step; returns (new state, loss, clipped mean gradient).
@@ -187,25 +224,34 @@ class ReferenceTrainer:
             batch = {k: half(v) for k, v in batch.items()}
         keep = np.zeros(self.ranks, np.float32)
         keep[: 1 if fault == "no_exchange" else self.ranks] = 1.0
-        loss, g = self._grads(st["params"], batch["tokens"], batch["labels"])
+        clock = _Clock(self.seconds)
+        replicated = NamedSharding(self.mesh, P())
+        loss, g = clock("gradients", self._grads(
+            self._to_f32(jax.device_put(st["params"], replicated)),
+            batch["tokens"], batch["labels"]))
         res = st["res"]
         if self.compressed:
-            stream, res = self._pack(g, res)
+            stream, res = clock("pack", self._pack(g, res))
             del g
             leaves, tdef = jax.tree.flatten(st["params"])
-            flat = self._decode(stream, keep)
+            flat = clock("decode", self._decode(stream, keep))
             del stream
             offs = np.cumsum([0] + [p.size for p in leaves])
             g = jax.tree.unflatten(tdef, [flat[a:b].reshape(p.shape) for a, b, p
                                           in zip(offs[:-1], offs[1:], leaves)])
+            del flat
         else:
-            g = jax.tree.map(lambda a: a.mean(0), g)
+            g = self._dense_mean(g)
         o = _Opt(self.t["optimizer"])
-        params, m, v, gc = self._adamw(st["params"], st["m"], st["v"], g,
+        put = lambda tree: jax.device_put(
+            tree, jax.tree.map(self._moment_sharding, st["params"]))
+        params, m, v, gc = self._adamw(jax.device_put(st["params"], replicated),
+                                       put(st["m"]), put(st["v"]), g,
                                        lr_at(st["step"], o), st["step"] + 1.0, o,
                                        1.5 if fault == "altered" else 1.0)
+        params, m, v = clock("update", jax.device_get((params, m, v)))
         new = {"params": params, "m": m, "v": v, "res": res, "step": st["step"] + 1}
-        return new, float(jnp.mean(loss)), gc
+        return new, float(jnp.mean(loss / self.accum)), gc
 
 
 class _Opt(dict):

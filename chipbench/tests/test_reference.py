@@ -64,3 +64,30 @@ def test_model_loss_matches_program(name):
     got = jnp.mean(jax.vmap(lambda a, b: fam.sequence_loss(
         params, a, b, cfg["model"], Dot("f32")))(toks, labs))
     assert abs(float(got) - float(want)) < 1e-5
+
+
+def test_codec_stopping_sets_match_program_oracle():
+    """The registry geometry on blocks near and above the peeling
+    threshold: some under capacity end in a stopping set, some stop at
+    the round cap, some are over capacity. Values and the unpeeled mask
+    agree with the program's peel."""
+    cfg = CompressionConfig(ratio=0.1, topk_ratio=0.04)
+    geo = codec.Geometry(dict(rows=6, lanes=512, rounds=10, seed=cfg.seed, ratio=0.1))
+    rng = np.random.default_rng(0)
+    nnz = list(np.linspace(1800, 2600, 16).astype(int)) + [6000, 12000]
+    x = np.zeros((len(nnz), cfg.group * cfg.lanes), np.float32)
+    for b, k in enumerate(nnz):
+        at = rng.choice(x.shape[1], k, replace=False)
+        x[b, at] = rng.normal(size=k)
+    x = jnp.asarray(x.reshape(len(nnz), cfg.group, cfg.lanes))
+    bits, ids = x != 0, jnp.arange(len(nnz), dtype=jnp.int32) + 5
+    sk = encode_blocks(x, ids, cfg)
+    want = peel_blocks(sk, bits, ids, cfg)
+    vals, left = codec.decode(sk, bits, ids, geo)
+    assert bool(jnp.all(left == want.residual))
+    np.testing.assert_allclose(vals, want.values, atol=1e-5)
+    # a block under capacity (rows * lanes / 1.23) that no number of rounds peels
+    stuck = codec.decode(sk, bits, ids, codec.Geometry(
+        dict(rows=6, lanes=512, rounds=200, seed=cfg.seed, ratio=0.1)))[1].sum((1, 2))
+    cap = 6 * 512 / 1.23
+    assert any(int(s) > 0 and k < cap for s, k in zip(stuck, nnz))
